@@ -424,9 +424,11 @@ def agg_smoke(n=64) -> int:
         if name == "topk":
             from repro.kernels.tune import Plan
             r = flat.wire_layout(spec, ccfg).runs[0]
-            sl = slice(r.koff, r.koff + r.nblocks * r.k)
-            vals = msgs.values[:, sl].reshape(n, r.nblocks, r.k)
-            idx = msgs.indices[:, sl].reshape(n, r.nblocks, r.k)
+            # stored slot rows are padded to r.ks lanes; the first r.k
+            # slots of each row are the payload
+            sl = slice(r.koff, r.koff + r.nblocks * r.ks)
+            vals = msgs.values[:, sl].reshape(n, r.nblocks, r.ks)[..., :r.k]
+            idx = msgs.indices[:, sl].reshape(n, r.nblocks, r.ks)[..., :r.k]
             base = None
             for plan in (Plan("scatter"), Plan("gemm", {"chunk": 8}),
                          Plan("onehot", {"chunk": 8}),

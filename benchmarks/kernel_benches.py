@@ -16,6 +16,7 @@ def kernel_topk():
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (8, 256))
     us, (v, i) = timed(lambda a: block_topk(a, 26), x)
+    v = v[:, :26]                   # slot rows come padded to 128 lanes
     vr, ir = ref.block_topk_ref(x, 26)
     err = float(np.max(np.abs(np.sort(np.asarray(v)) - np.sort(np.asarray(vr)))))
     emit("kernel_topk_block_8x256_k26", us, f"max_err_vs_ref={err:.2e}")

@@ -395,6 +395,9 @@ def sharded_records(out: str = "BENCH_scale.json"):
     import subprocess
 
     env = dict(os.environ)
+    # forced host devices exist only on the CPU backend; pinning it also
+    # keeps the child off any accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()
     proc = subprocess.run(

@@ -21,7 +21,12 @@ buffer with static slice metadata and gives the engine:
 * :class:`WireLayout` -- static per-leaf block geometry (offsets, block
   sizes, top-k slots, packed-word counts) with consecutive same-geometry
   leaves merged into *runs*: one pack / kernel call per run instead of per
-  leaf x client,
+  leaf x client.  In a large run each block's payload row (its slots, or
+  its packed words) is stored padded to a multiple of 128 lanes, so the
+  run's ``[*lead, nblocks, width]`` payload view and the flat payload
+  buffer share one TPU tiling: at full width the re-tiling copy between
+  the two would dominate the round's compile.  Pad entries are zeros and
+  are not wire bytes,
 * :class:`FlatTransport` -- the flat mirror of :class:`repro.comm.Transport`
   (same ``encode`` / ``reduce`` / ``transmit`` / ``broadcast`` contract, so
   ``engine.participation`` dispatches to it unchanged) with the flat wire
@@ -55,6 +60,14 @@ from repro.obs import trace as obs_trace
 from repro.sharding import partition
 
 tree_map = jax.tree_util.tree_map
+
+# Above this many bytes of participant deltas, the gathered EF encode runs
+# one participant at a time (FlatTransport._ef_rows): an [m, d] stack of
+# each EF temporary does not fit one chip at full width.  Below it the
+# encode stays vectorized over the participants, which keeps it bit-equal
+# to the mask path (per-row programs may contract multiply-adds
+# differently).
+ROW_LOOP_BYTES = 1 << 30
 
 
 # ---------------------------------------------------------------------------
@@ -172,6 +185,7 @@ class LeafWire(NamedTuple):
     nblocks: int            # lead * (D // block)
     k: int                  # select kinds: slots per block
     sort_free: bool         # giant leaf: threshold selection regime
+    ks: int = 0             # stored slots per block (its run's)
 
 
 class RunSpec(NamedTuple):
@@ -183,18 +197,36 @@ class RunSpec(NamedTuple):
     nblocks: int
     k: int
     sort_free: bool
-    koff: int               # cumulative slot offset in the payload
+    koff: int               # cumulative slot offset in the stored payload
     boff: int               # cumulative block offset (quant scales)
-    woff: int               # cumulative word offset (quant words)
-    W: int                  # words per block
+    woff: int               # cumulative word offset in the stored payload
+    W: int                  # words per block on the wire
+    ks: int                 # stored slots per block (k, or k padded to 128
+                            # lanes in a large run)
+    Ws: int                 # stored words per block (likewise for W)
 
 
 class WireLayout(NamedTuple):
     leaves: tuple           # tuple[LeafWire]
     runs: tuple             # tuple[RunSpec]
-    K_total: int
+    K_total: int            # stored slots (pad included)
     NB_total: int
-    W_total: int
+    W_total: int            # stored words (pad included)
+    K_wire: int             # slots on the wire
+    W_wire: int             # words on the wire
+
+
+# A run's payload rows are stored padded to 128 lanes once one client's
+# stored payload of the run reaches this many entries: a small run re-tiles
+# in no time, and unpadded rows keep payload shapes (hence the wire frame
+# signatures) distinct across compressor geometries.
+PAD_ROWS_FROM = 1 << 16
+
+
+def _stored(nblocks: int, width: int) -> int:
+    if nblocks * width < PAD_ROWS_FROM:
+        return width
+    return -(-width // 128) * 128
 
 
 _LAYOUT_CACHE: dict = {}
@@ -217,42 +249,34 @@ def wire_layout(spec: FlatSpec, cfg: CompressorConfig) -> WireLayout:
         k = max(1, min(b, int(round(b * cfg.ratio))))
         lws.append(LeafWire(ls.offset, lead, D, b, lead * (D // b), k,
                             ls.size > _SORT_FREE_MIN))
-    runs, koff, boff, woff = [], 0, 0, 0
-    for lw in lws:
-        W = words_per_block(lw.block, pw_bits)
+    runs, members = [], []
+    for i, lw in enumerate(lws):
         if runs and runs[-1].block == lw.block and runs[-1].k == lw.k \
                 and runs[-1].sort_free == lw.sort_free:
             r = runs[-1]
             runs[-1] = r._replace(span=r.span + lw.lead * lw.D,
                                   nblocks=r.nblocks + lw.nblocks)
+            members[-1].append(i)
         else:
+            W = words_per_block(lw.block, pw_bits)
             runs.append(RunSpec(lw.offset, lw.lead * lw.D, lw.block,
                                 lw.nblocks, lw.k, lw.sort_free,
-                                koff, boff, woff, W))
-        koff += lw.nblocks * lw.k
-        boff += lw.nblocks
-        woff += lw.nblocks * W
-    out = WireLayout(tuple(lws), tuple(runs), koff, boff, woff)
+                                0, 0, 0, W, 0, 0))
+            members.append([i])
+    koff = boff = woff = kw = ww = 0
+    for j, r in enumerate(runs):
+        ks, Ws = _stored(r.nblocks, r.k), _stored(r.nblocks, r.W)
+        runs[j] = r._replace(koff=koff, boff=boff, woff=woff, ks=ks, Ws=Ws)
+        for i in members[j]:
+            lws[i] = lws[i]._replace(ks=ks)
+        koff += r.nblocks * ks
+        boff += r.nblocks
+        woff += r.nblocks * Ws
+        kw += r.nblocks * r.k
+        ww += r.nblocks * r.W
+    out = WireLayout(tuple(lws), tuple(runs), koff, boff, woff, kw, ww)
     _LAYOUT_CACHE[sig] = out
     return out
-
-
-_BASE_CACHE: dict = {}
-
-
-def base_positions(layout: WireLayout) -> jnp.ndarray:
-    """[K_total] int32: flat position of slot t's block start -- the static
-    half of the payload-domain scatter (``pos = base + within_block_idx``)."""
-    hit = _BASE_CACHE.get(layout)
-    if hit is None:
-        if len(_BASE_CACHE) > 64:
-            _BASE_CACHE.clear()
-        parts = [np.repeat(r.offset + np.arange(r.nblocks, dtype=np.int64)
-                           * r.block, r.k) for r in layout.runs]
-        # cache host-side: a device array created under a trace would leak
-        # its tracer into later jit scopes
-        hit = _BASE_CACHE[layout] = np.concatenate(parts).astype(np.int32)
-    return jnp.asarray(hit)
 
 
 def _run_view(flat: jnp.ndarray, r: RunSpec) -> jnp.ndarray:
@@ -260,6 +284,28 @@ def _run_view(flat: jnp.ndarray, r: RunSpec) -> jnp.ndarray:
     lead = flat.shape[:-1]
     return flat[..., r.offset:r.offset + r.span].reshape(
         lead + (r.nblocks, r.block))
+
+
+def _rows(payload: jnp.ndarray, off: int, nblocks: int,
+          width: int) -> jnp.ndarray:
+    """A run's stored payload rows: [*lead, nblocks * width] slice at
+    ``off`` viewed as [*lead, nblocks, width]."""
+    lead = payload.shape[:-1]
+    return payload[..., off:off + nblocks * width].reshape(
+        lead + (nblocks, width))
+
+
+def _pad_rows(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Zero-pad the last axis of [*lead, nblocks, w] payload rows to the
+    stored ``width`` and flatten the run to [*lead, nblocks * width]."""
+    if x.shape[-1] < width:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                    + [(0, width - x.shape[-1])])
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * width,))
+
+
+def _cat(xs):
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -279,34 +325,31 @@ class _SelectCodec:
 
     def pack(self, buf: jnp.ndarray, key=None) -> FlatPacked:
         """[*lead, d] -> FlatPacked [*lead, K_total]; one selection op (or
-        one ``topk_block`` kernel launch) per run, the client axis folded
-        into the run's block rows."""
-        lead = buf.shape[:-1]
+        one ``topk_block`` kernel launch) per run over its
+        [*lead, nblocks, block] view."""
         vs, js = [], []
         for r in self.layout.runs:
             blocks = _run_view(buf, r)
             if self.pallas and r.k < r.block:
                 from repro.kernels.topk_block import block_topk
-                vals, idx = block_topk(blocks.reshape(-1, r.block), r.k)
-                vals = vals.reshape(lead + (r.nblocks, r.k))
-                idx = idx.reshape(lead + (r.nblocks, r.k)).astype(INDEX_DTYPE)
+                # slot rows come padded to 128 lanes; a small run stores k
+                vals, idx = block_topk(blocks, r.k)
+                vals = vals[..., :r.ks]
+                idx = idx[..., :r.ks].astype(INDEX_DTYPE)
             else:
                 vals, idx = payloads.select_topk_blocks(blocks, r.k,
                                                         r.sort_free)
-            vs.append(vals.reshape(lead + (r.nblocks * r.k,)))
-            js.append(idx.reshape(lead + (r.nblocks * r.k,)))
-        cat = (lambda xs: xs[0] if len(xs) == 1
-               else jnp.concatenate(xs, axis=-1))
-        return FlatPacked(cat(vs), cat(js))
+            vs.append(_pad_rows(vals, r.ks))
+            js.append(_pad_rows(idx, r.ks))
+        return FlatPacked(_cat(vs), _cat(js))
 
     def decode(self, p: FlatPacked) -> jnp.ndarray:
         """FlatPacked -> dense [*lead, d] (zeros off-support)."""
         lead = p.values.shape[:-1]
         outs = []
         for r in self.layout.runs:
-            sl = slice(r.koff, r.koff + r.nblocks * r.k)
-            vals = p.values[..., sl].reshape(lead + (r.nblocks, r.k))
-            idx = p.indices[..., sl].reshape(lead + (r.nblocks, r.k))
+            vals = _rows(p.values, r.koff, r.nblocks, r.ks)[..., :r.k]
+            idx = _rows(p.indices, r.koff, r.nblocks, r.ks)[..., :r.k]
             dense = jnp.zeros(lead + (r.nblocks, r.block), p.values.dtype)
             dense = jnp.put_along_axis(dense, idx.astype(jnp.int32), vals,
                                        axis=-1, inplace=False)
@@ -320,20 +363,20 @@ class _SelectCodec:
         (:func:`repro.kernels.ops.scatter_agg`) -- no sequential per-client
         dense decompression, and no serialized general scatter."""
         from repro.kernels import ops
-        n = p.values.shape[0]
         outs = []
         for r in self.layout.runs:
-            sl = slice(r.koff, r.koff + r.nblocks * r.k)
-            vals = p.values[:, sl].reshape(n, r.nblocks, r.k)
-            idx = p.indices[:, sl].reshape(n, r.nblocks, r.k)
-            acc = ops.scatter_agg(vals, idx, weights, block=r.block)
+            # the pad slots hold zero values: the kernel reads the stored
+            # rows whole and skips them, the XLA plans take the k real ones
+            vals = _rows(p.values, r.koff, r.nblocks, r.ks)
+            idx = _rows(p.indices, r.koff, r.nblocks, r.ks)
+            acc = ops.scatter_agg(vals, idx, weights, block=r.block, k=r.k)
             outs.append(acc.reshape(r.span))
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
         return out.astype(self.spec.dtype) / m
 
     def wire_bytes(self) -> int:
         itemsize = jnp.dtype(self.spec.dtype).itemsize
-        return int(self.layout.K_total
+        return int(self.layout.K_wire
                    * (itemsize + jnp.dtype(INDEX_DTYPE).itemsize))
 
 
@@ -352,8 +395,9 @@ class _RandkCodec(_SelectCodec):
             leaf = buf[ls.offset:ls.offset + ls.size].reshape(
                 ls.shape if ls.shape else (1,))
             p = payloads.block_randk_pack(leaf, self.cfg, k_leaf)
-            vs.append(p.values.reshape(-1))
-            js.append(p.indices.reshape(-1))
+            rows = lambda x: x.reshape(lw.nblocks, lw.k)  # noqa: E731
+            vs.append(_pad_rows(rows(p.values), lw.ks))
+            js.append(_pad_rows(rows(p.indices), lw.ks))
         return FlatPacked(jnp.concatenate(vs), jnp.concatenate(js))
 
 
@@ -371,24 +415,20 @@ class _QuantCodec:
         self.levels = float(2 ** (cfg.bits - 1) - 1)
 
     def pack(self, buf: jnp.ndarray, key=None) -> FlatQuant:
-        lead = buf.shape[:-1]
         ws, ss = [], []
         for r in self.layout.runs:
             blocks = _run_view(buf, r)
             codes, scale = payloads.quant_blocks(blocks, self.cfg.bits)
             words = pack_codes(codes.astype(jnp.int32), self.cfg.bits)
-            ws.append(words.reshape(lead + (r.nblocks * r.W,)))
-            ss.append(scale.astype(jnp.float32).reshape(lead + (r.nblocks,)))
-        cat = (lambda xs: xs[0] if len(xs) == 1
-               else jnp.concatenate(xs, axis=-1))
-        return FlatQuant(cat(ws), cat(ss))
+            ws.append(_pad_rows(words, r.Ws))
+            ss.append(scale[..., 0].astype(jnp.float32))
+        return FlatQuant(_cat(ws), _cat(ss))
 
     def decode(self, q: FlatQuant) -> jnp.ndarray:
         lead = q.words.shape[:-1]
         outs = []
         for r in self.layout.runs:
-            words = q.words[..., r.woff:r.woff + r.nblocks * r.W].reshape(
-                lead + (r.nblocks, r.W))
+            words = _rows(q.words, r.woff, r.nblocks, r.Ws)[..., :r.W]
             scale = q.scale[..., r.boff:r.boff + r.nblocks][..., None]
             codes = unpack_codes(words, self.cfg.bits, r.block)
             vals = codes.astype(self.spec.dtype) / self.levels * scale
@@ -398,14 +438,12 @@ class _QuantCodec:
 
     def reduce(self, q: FlatQuant, weights: jnp.ndarray, m) -> jnp.ndarray:
         from repro.kernels import ops, tune
-        n = q.words.shape[0]
         # the pallas backend pins the fused unpack_mma kernel (documented
         # backend semantics); others take the tuner's plan for the shape
         plan = tune.Plan("pallas") if self.pallas else None
         outs = []
         for r in self.layout.runs:
-            words = q.words[:, r.woff:r.woff + r.nblocks * r.W].reshape(
-                n, r.nblocks, r.W)
+            words = _rows(q.words, r.woff, r.nblocks, r.Ws)
             scale = q.scale[:, r.boff:r.boff + r.nblocks]
             acc = ops.quant_agg(words, scale, weights, self.cfg.bits,
                                 r.block, plan=plan)
@@ -414,7 +452,7 @@ class _QuantCodec:
         return out.astype(self.spec.dtype) / m
 
     def wire_bytes(self) -> int:
-        return int(4 * (self.layout.W_total + self.layout.NB_total))
+        return int(4 * (self.layout.W_wire + self.layout.NB_total))
 
 
 class _QuantPallasCodec(_QuantCodec):
@@ -431,16 +469,16 @@ class _QuantPallasCodec(_QuantCodec):
         lead = deltas.shape[:-1]
         ws, ss, es = [], [], []
         for r in self.layout.runs:
-            e_run = _run_view(e, r).reshape(-1, r.block)
-            d_run = _run_view(deltas, r).reshape(-1, r.block)
-            words, scale, e_new = quantize_ef_pack(e_run, d_run,
-                                                   self.cfg.bits)
-            ws.append(words.reshape(lead + (r.nblocks * r.W,)))
-            ss.append(scale.reshape(lead + (r.nblocks,)))
+            # [*lead, nblocks, block] views: the client axis stays a batch
+            # axis of the kernel grid (merging it into the block rows
+            # would re-tile the whole buffer on a TPU)
+            words, scale, e_new = quantize_ef_pack(
+                _run_view(e, r), _run_view(deltas, r), self.cfg.bits)
+            # word rows come padded to 128 lanes; a small run stores W
+            ws.append(words[..., :r.Ws].reshape(lead + (r.nblocks * r.Ws,)))
+            ss.append(scale)
             es.append(e_new.reshape(lead + (r.span,)))
-        cat = (lambda xs: xs[0] if len(xs) == 1
-               else jnp.concatenate(xs, axis=-1))
-        return FlatQuant(cat(ws), cat(ss)), cat(es)
+        return FlatQuant(_cat(ws), _cat(ss)), _cat(es)
 
     def pack(self, buf: jnp.ndarray, key=None) -> FlatQuant:
         msg, _ = self.ef(jnp.zeros_like(buf), buf)
@@ -617,19 +655,61 @@ class FlatTransport:
         n = mask.shape[0]
         if self.is_identity:
             return transports.scatter_rows(deltas, idx, n), e
-        e_part = None if e is None else jnp.take(e, idx, axis=0)
         keys = None
         if self.needs_key and key is not None:
             keys = jnp.take(jax.random.split(key, n), idx, axis=0)
-        msgs, e_stack = self._ef_clients(e_part, deltas, key, keys=keys)
-        e_out = e
-        if e is not None:
-            e_stack = partition.constrain_leading(e_stack, "client")
-            e_out = e.at[idx].set(e_stack)
+        if e is not None and self.codec is not None \
+                and deltas.size * deltas.dtype.itemsize > ROW_LOOP_BYTES:
+            msgs, e_out = self._ef_rows(e, deltas, idx, key, keys)
+            e_out = partition.constrain_leading(e_out, "client")
+        else:
+            e_part = None if e is None else transports.take_rows(e, idx)
+            msgs, e_stack = self._ef_clients(e_part, deltas, key, keys=keys)
+            e_out = e if e is None else partition.constrain_leading(
+                transports.put_rows(e, idx, e_stack), "client")
         msgs = transports.scatter_rows(msgs, idx, n)
         if self.wire == "dense":
             msgs = partition.constrain_leading(msgs, "client")
         return msgs, e_out
+
+    def _ef_rows(self, e, deltas, idx, key, keys):
+        """EF14 encode of the m participants one at a time: row ``j`` reads
+        the residual ``e[idx[j]]`` and writes its new residual back in
+        place, so the round holds one participant's EF temporaries, not an
+        [m, d] stack of each (which does not fit one chip at full width).
+        A repeated id (a realized cohort padded by ``mask_indices``) takes
+        the message and residual of its first occurrence, as the
+        vectorized encode of identical rows would.  Returns (stacked
+        [m, ...] messages, updated e)."""
+        m = deltas.shape[0]
+
+        def row(x, j):
+            return jax.lax.dynamic_slice_in_dim(x, j, 1, axis=0)
+
+        def encode_row(e_j, d_j, k_j):
+            return self._ef_clients(e_j, d_j, key, keys=k_j)
+
+        k0 = None if keys is None else keys[:1]
+        shapes, _ = jax.eval_shape(encode_row, e[:1], deltas[:1], k0)
+        msgs0 = tree_map(lambda s: jnp.zeros((m,) + s.shape[1:], s.dtype),
+                         shapes)
+
+        def body(j, carry):
+            msgs, e = carry
+            e_j = row(e, idx[j])
+            msg_j, e_new = encode_row(e_j, row(deltas, j),
+                                      None if keys is None else row(keys, j))
+            first = jnp.argmax(idx == idx[j])
+            dup = first < j
+            msg_j = tree_map(lambda M, x: jnp.where(dup, row(M, first), x),
+                             msgs, msg_j)
+            e_new = jnp.where(dup, e_j, e_new.astype(e.dtype))
+            msgs = tree_map(
+                lambda M, x: jax.lax.dynamic_update_slice_in_dim(M, x, j, 0),
+                msgs, msg_j)
+            return msgs, jax.lax.dynamic_update_slice_in_dim(e, e_new,
+                                                             idx[j], 0)
+        return jax.lax.fori_loop(0, m, body, (msgs0, e))
 
     def reduce_single(self, msgs, weights, m, like=None) -> jnp.ndarray:
         """The single-tier weighted aggregation of stacked wire messages

@@ -43,8 +43,9 @@ class QuantPayload(NamedTuple):
 class FlatPacked(NamedTuple):
     """Block-select payload of a flat [d] buffer (comm.flat): the values and
     within-block offsets of every block of every leaf, concatenated in leaf
-    order.  Static block geometry (base positions per slot) lives in the
-    :class:`repro.comm.flat.WireLayout`, not on the wire."""
+    order; in a large run each block's slot row is zero-padded to 128 lanes
+    (pad slots hold value 0 at offset 0 and are not wire bytes).  Static block geometry
+    lives in the :class:`repro.comm.flat.WireLayout`, not on the wire."""
     values: jnp.ndarray     # [..., K_total] buffer dtype
     indices: jnp.ndarray    # [..., K_total] uint16 within-block offsets
 
@@ -53,7 +54,8 @@ class FlatQuant(NamedTuple):
     """Bit-packed quantization payload of a flat [d] buffer: b-bit biased
     codes packed ``32 // b`` to a uint32 word (the true wire format -- HBM
     and collective traffic shrink 8/b x vs int8 words), plus one fp32
-    max-abs scale per block."""
+    max-abs scale per block.  In a large run each block's word row is
+    zero-padded to 128 lanes; the pad words are not wire bytes."""
     words: jnp.ndarray      # [..., W_total] uint32
     scale: jnp.ndarray      # [..., nblocks_total] float32
 

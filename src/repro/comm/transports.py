@@ -98,6 +98,42 @@ def mask_where(mask, new, old):
 _mask_where = mask_where        # internal alias (pre-async name)
 
 
+# Rows of at least this many elements move by a loop of dynamic row slices
+# (take_rows / put_rows): a row gather or scatter of a full-width [n, d]
+# buffer lowers on a TPU to one unrolled copy per 32k-element chunk
+# (minutes of compile, and a compiler stack overflow at 362M elements).
+# Smaller rows keep XLA's gather / scatter.
+ROW_LOOP_ELEMS = 1 << 20
+
+
+def take_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``x[idx]`` for a stacked [n, ...] array and in-range row ids; long
+    rows move one dynamic row slice per id (:data:`ROW_LOOP_ELEMS`)."""
+    if x[0].size < ROW_LOOP_ELEMS:
+        return jnp.take(x, idx, axis=0)
+
+    def body(j, out):
+        row = jax.lax.dynamic_slice_in_dim(x, idx[j], 1, axis=0)
+        return jax.lax.dynamic_update_slice_in_dim(out, row, j, axis=0)
+    out = jnp.zeros((idx.shape[0],) + x.shape[1:], x.dtype)
+    return jax.lax.fori_loop(0, idx.shape[0], body, out)
+
+
+def put_rows(x: jnp.ndarray, idx: jnp.ndarray,
+             rows: jnp.ndarray) -> jnp.ndarray:
+    """``x.at[idx].set(rows)`` for in-range row ids (a repeated id writes
+    one of its rows); long rows land one dynamic row update per id
+    (:data:`ROW_LOOP_ELEMS`)."""
+    if x[0].size < ROW_LOOP_ELEMS:
+        return x.at[idx].set(rows.astype(x.dtype))
+
+    def body(j, out):
+        row = jax.lax.dynamic_slice_in_dim(rows, j, 1, axis=0)
+        return jax.lax.dynamic_update_slice_in_dim(out, row.astype(out.dtype),
+                                                   idx[j], axis=0)
+    return jax.lax.fori_loop(0, idx.shape[0], body, x)
+
+
 def scatter_rows(tree, idx, n: int):
     """[m, ...] participant rows -> full [n, ...] layout, zeros elsewhere.
     Works on dense leaves and payload pytrees alike (payload fields carry
@@ -108,17 +144,19 @@ def scatter_rows(tree, idx, n: int):
     leaves route through the tuned :func:`repro.kernels.ops.segment_rows`
     when the backend plan selects the Pallas segment kernel; otherwise
     (and always for integer wire fields -- packed words / offsets must
-    round-trip bit-exactly, a float one-hot contraction would not) the XLA
-    ``.at[idx].set`` scatter runs unchanged."""
+    round-trip bit-exactly, a float one-hot contraction would not) the rows
+    land by :func:`put_rows`.  Under an active mesh they always do: the
+    compiler does not partition a Pallas kernel across devices."""
     from repro.kernels import ops, tune
+    from repro.sharding import partition
 
     def one(x):
-        if jnp.issubdtype(x.dtype, jnp.floating):
+        if jnp.issubdtype(x.dtype, jnp.floating) \
+                and partition.current_mesh() is None:
             plan = tune.get_plan("segment_rows", m=x.shape[0], n=n)
             if plan.impl == "pallas":
                 return ops.segment_rows(x, idx, n, plan=plan)
-        out = jnp.zeros((n,) + x.shape[1:], x.dtype)
-        return out.at[idx].set(x)
+        return put_rows(jnp.zeros((n,) + x.shape[1:], x.dtype), idx, x)
     return tree_map(one, tree)
 
 
@@ -279,7 +317,7 @@ class Transport:
         from repro.sharding import partition
         n = mask.shape[0]
         e_part = None if e is None else \
-            tree_map(lambda x: jnp.take(x, idx, axis=0), e)
+            tree_map(lambda x: take_rows(x, idx), e)
         keys = None
         if self.needs_key and key is not None:
             keys = jnp.take(jax.random.split(key, n), idx, axis=0)
@@ -287,7 +325,7 @@ class Transport:
         e_out = e
         if e is not None:
             e_stack = partition.constrain_leading(e_stack, "client")
-            e_out = tree_map(lambda E, En: E.at[idx].set(En), e, e_stack)
+            e_out = tree_map(lambda E, En: put_rows(E, idx, En), e, e_stack)
         msgs = scatter_rows(msgs, idx, n)
         if self.wire == "dense":
             msgs = partition.constrain_leading(msgs, "client")
@@ -497,10 +535,9 @@ class TopKTransport(_BlockSelectTransport):
             idx = jnp.broadcast_to(
                 jnp.arange(b, dtype=payloads.INDEX_DTYPE), blocks.shape).copy()
             return PackedLeaf(blocks, idx)
-        lead = blocks.shape[:-1]
-        vals, idx = block_topk(blocks.reshape(-1, b), k)
-        return PackedLeaf(vals.reshape(lead + (k,)),
-                          idx.reshape(lead + (k,)).astype(payloads.INDEX_DTYPE))
+        vals, idx = block_topk(blocks, k)
+        return PackedLeaf(vals[..., :k],
+                          idx[..., :k].astype(payloads.INDEX_DTYPE))
 
     def _ef_clients(self, e, deltas, like, key, keys=None):
         if self.backend != "pallas":
@@ -609,7 +646,8 @@ class QuantTransport(Transport):
                 return buf, jnp.zeros_like(buf)
             D = ej.shape[-1]
             b = choose_block(D, self.cfg.block, self.cfg.shards)
-            v, en = quantize_ef(ej.reshape(-1, b), dj.reshape(-1, b),
+            blocks = ej.shape[:-1] + (D // b, b)
+            v, en = quantize_ef(ej.reshape(blocks), dj.reshape(blocks),
                                 self.cfg.bits)
             return v.reshape(ej.shape), en.reshape(ej.shape)
 

@@ -111,7 +111,9 @@ def init_state(params, cfg: FedConfig, key: Optional[jax.Array] = None) -> FedSt
             # the flat hot path (comm.flat): ONE [n, d] buffer instead of n
             # stacked pytrees -- every EF elementwise op is a single kernel
             e_up = jnp.zeros((cfg.n_clients, spec.d), spec.dtype)
-    x = params if downlink.tracks_center else None
+    # x gets buffers of its own: round_program donates the state, and a
+    # buffer cannot be donated twice (as w and as x)
+    x = tree_map(jnp.copy, params) if downlink.tracks_center else None
     samp = samplers.get_sampler(cfg.fleet.sampler)
     return FedState(
         w=params, x=x, e_up=e_up,
@@ -508,18 +510,31 @@ def _drive_loop(step: Callable, carry, batches, T: int, *,
     return carry, stacked
 
 
+def round_program(loss_pair: Callable, cfg: FedConfig):
+    """The jitted one-round program :func:`run_rounds` drives.  On every
+    backend but the CPU (which ignores donation) it takes the state's
+    buffers, so a full-width state is never held twice; the caller's state
+    is consumed."""
+    donate = (0,) if jax.default_backend() != "cpu" else ()
+    return jax.jit(lambda s, b: round_step(s, b, loss_pair, cfg),
+                   donate_argnums=donate)
+
+
 def run_rounds(state: FedState, batch_fn: Callable, loss_pair: Callable,
-               cfg: FedConfig, T: int, jit: bool = True):
+               cfg: FedConfig, T: int, jit: bool = True,
+               step: Optional[Callable] = None):
     """Drive T rounds; ``batch_fn(t, key) -> batches`` supplies per-round
     data (host-side loop so batch_fn may be arbitrary python; the round
-    itself is jitted).
+    itself is jitted -- ``step`` passes a :func:`round_program` built by the
+    caller).
 
     Compatibility shim over the engine round.  Metrics accumulate on device
     and transfer to the host once at the end -- the seed's per-round
     ``jax.device_get`` stalled dispatch between rounds.
     """
-    step = jax.jit(lambda s, b: round_step(s, b, loss_pair, cfg)) if jit else \
-        (lambda s, b: round_step(s, b, loss_pair, cfg))
+    if step is None:
+        step = round_program(loss_pair, cfg) if jit else \
+            (lambda s, b: round_step(s, b, loss_pair, cfg))
     history = []
     key = jax.random.PRNGKey(cfg.seed + 1)
     for t in range(T):

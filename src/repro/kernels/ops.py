@@ -15,7 +15,6 @@ import jax.numpy as jnp
 
 from repro.kernels import tune
 from repro.kernels.quantize_ef import quantize_ef
-from repro.kernels.quantize_ef_pack import quantize_ef_pack
 from repro.kernels.switch_blend import switch_blend
 from repro.kernels.topk_block import block_topk
 from repro.kernels.unpack_mma import unpack_mma
@@ -40,6 +39,7 @@ def topk_compress(x: jnp.ndarray, ratio: float, block: int = 1024,
         return x
     with obs_trace.stage("kernel.block_topk"):
         vals, idx = block_topk(blocks, k, interpret=interpret)
+    vals, idx = vals[:, :k], idx[:, :k]
     dense = jnp.zeros_like(blocks)
     dense = jax.vmap(lambda dst, i, v: dst.at[i].set(v))(dense, idx, vals)
     return dense.reshape(-1)[:d].reshape(x.shape)
@@ -54,31 +54,6 @@ def quantize_ef_apply(e: jnp.ndarray, delta: jnp.ndarray, bits: int,
         v, e_new = quantize_ef(eb, db, bits, interpret=interpret)
     unb = lambda t: t.reshape(-1)[:d].reshape(e.shape)
     return unb(v), unb(e_new)
-
-
-def quantize_ef_pack_apply(e: jnp.ndarray, delta: jnp.ndarray, bits: int,
-                           block: int = 1024, interpret: bool | None = None):
-    """Fused EF14 quantize-and-bit-pack for arbitrary-shape arrays:
-    returns (words uint32 [nblocks, W], scale f32 [nblocks, 1], e_new like
-    ``e``) -- the wire words ship 32//bits codes per uint32."""
-    eb, d = _to_blocks(e, block)
-    db, _ = _to_blocks(delta, block)
-    with obs_trace.stage("kernel.quantize_ef_pack"):
-        words, scale, e_new = quantize_ef_pack(eb, db, bits,
-                                               interpret=interpret)
-    return words, scale, e_new.reshape(-1)[:d].reshape(e.shape)
-
-
-def unpack_mma_apply(words: jnp.ndarray, scale: jnp.ndarray,
-                     weight: jnp.ndarray, bits: int, block: int,
-                     interpret: bool | None = None) -> jnp.ndarray:
-    """Fused unpack-multiply-add aggregation of stacked client payloads:
-    words [n, nblocks, W] + scale [n, nblocks] + weight [n] -> the weighted
-    payload-domain sum [nblocks * block] (flat)."""
-    with obs_trace.stage("kernel.unpack_mma"):
-        acc = unpack_mma(words, scale, weight, bits, block,
-                         interpret=interpret)
-    return acc.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -173,23 +148,29 @@ def _scatter_agg_gemm(vals, idx, weight, block, chunk):
 
 def scatter_agg(vals: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
                 *, block: int, plan: tune.Plan | None = None,
+                k: int | None = None,
                 interpret: bool | None = None) -> jnp.ndarray:
     """Weighted bucket aggregation of stacked select payloads: vals
-    [n, nblocks, k] + within-block offsets idx [n, nblocks, k] (in
+    [n, nblocks, slots] + within-block offsets idx [n, nblocks, slots] (in
     [0, block)) + weight [n] -> [nblocks, block] f32 with
 
-        out[b, o] = sum_j sum_t weight[j] * vals[j,b,t] * 1[idx[j,b,t]==o].
+        out[b, o] = sum_j sum_{t<k} weight[j] * vals[j,b,t] * 1[idx[j,b,t]==o].
 
-    Duplicate offsets within a block accumulate.  The implementation is the
-    tuner's plan for this shape (``gemm`` factored one-hot batch-matmul on
-    CPU, ``onehot`` chunked contraction as the simpler alternative, the
-    Pallas bucket kernel on TPU, native ``scatter`` on GPU)."""
-    n, nb, k = vals.shape
+    ``k`` (default: every slot) counts the real slots of a row; the flat
+    wire buffers pad slot rows to 128 lanes.  Duplicate offsets within a
+    block accumulate.  The implementation is the tuner's plan for this
+    shape (``gemm`` factored one-hot batch-matmul on CPU, ``onehot``
+    chunked contraction as the simpler alternative, the Pallas bucket
+    kernel on TPU, native ``scatter`` on GPU)."""
+    n, nb, slots = vals.shape
+    k = slots if k is None else k
     if block == 1:
         return jnp.tensordot(weight.astype(jnp.float32),
-                             vals.astype(jnp.float32), axes=(0, 0))
+                             vals[..., :k].astype(jnp.float32), axes=(0, 0))
     if plan is None:
         plan = tune.get_plan("scatter_agg", n=n, nblocks=nb, k=k, block=block)
+    if plan.impl != "pallas":
+        vals, idx = vals[..., :k], idx[..., :k]     # the kernel skips pads
     with obs_trace.stage(f"kernel.scatter_agg[{plan.impl}]"):
         if plan.impl == "gemm":
             return _scatter_agg_gemm(vals, idx, weight, block,
@@ -200,7 +181,7 @@ def scatter_agg(vals: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
         if plan.impl == "pallas":
             from repro.kernels.scatter_agg import scatter_agg as kernel
             return kernel(vals, idx, weight, block,
-                          rows=int(plan.params.get("rows", 8)),
+                          rows=int(plan.params.get("rows", 8)), k=k,
                           interpret=interpret)
         return _scatter_agg_scatter(vals, idx, weight, block)
 

@@ -7,15 +7,22 @@ wire payload.
     words = pack_b(codes + L)             (32 // b biased lanes per uint32)
     e'    = buf - codes / L * scale
 
-One pass over the VMEM-resident block produces the *wire words* directly --
+One pass over the VMEM-resident blocks produces the *wire words* directly --
 the int8/int32 code tensor of the unfused path never exists, so packed-mode
-HBM traffic out of the encode step is the true ``b/32``-word stream (8/b x
-smaller than int8 codes) and the EF residual update still rides the same
-block visit (no second HBM round-trip of e + delta).
+HBM traffic out of the encode step is the true ``b/32``-word stream and the
+EF residual update rides the same block visit.
 
-Lane assembly uses ``per_word`` strided slices + shifts (no in-kernel
-gather); blocks whose size is not a multiple of 32//b zero-pad the trailing
-word's lanes, matching :func:`repro.comm.payloads.pack_codes` bit-for-bit.
+Packing is a matmul on the MXU.  Lane ``c`` of a block belongs to word
+``c // per_word`` at bit offset ``b * (c % per_word)``
+(:func:`repro.comm.payloads.pack_codes`).  Biased codes are integers in
+[0, 2L] <= 254 and the place values ``2^(b*i)`` are powers of two, so both
+are exact in bf16, and each output sums codes with disjoint bit ranges.
+Splitting a word into its low and high 16 bits keeps every sum below
+2^16, exact in the f32 accumulator.  ``codes[rows, lanes] @ P[lanes, 2W]``
+therefore yields both half-words of every word exactly, with no lane-strided
+slice (which Mosaic does not lower).  The word rows leave padded to 128
+lanes and the per-block scales leave lane-dense (one transpose per tile),
+so neither output needs a re-tiling copy at full width.
 """
 from __future__ import annotations
 
@@ -24,59 +31,84 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tiling
+from repro.kernels.quantize_ef import quantize_rows
 
 
-def _kernel(e_ref, d_ref, words_ref, scale_ref, enew_ref, *,
-            bits: int, block: int):
-    per_word = 32 // bits
-    W = words_ref.shape[-1]
-    levels = 2 ** (bits - 1) - 1
+def place_values(block: int, bits: int) -> jnp.ndarray:
+    """[lanes(block), 2 * lanes(W)] bf16: column ``w`` (low half-word) and
+    ``lanes(W) + w`` (high half-word) hold the place value of each code
+    lane of word ``w``; lanes past ``block`` map to zero bits."""
+    from repro.comm.payloads import words_per_block
+    per_word, half = 32 // bits, 16 // bits
+    wp = tiling.lanes(words_per_block(block, bits))
+    c = jax.lax.broadcasted_iota(jnp.int32, (tiling.lanes(block), 2 * wp), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tiling.lanes(block), 2 * wp), 1)
+    lane_in_word = c % per_word
+    hi = lane_in_word >= half
+    hit = (c < block) & (col == c // per_word + jnp.where(hi, wp, 0))
+    power = jnp.left_shift(1, bits * (lane_in_word - jnp.where(hi, half, 0)))
+    return jnp.where(hit, power, 0).astype(jnp.bfloat16)
 
-    buf = e_ref[0, :] + d_ref[0, :]
-    scale = jnp.max(jnp.abs(buf))
-    lv = jnp.asarray(float(levels), buf.dtype)
-    safe = jnp.where(scale > 0, scale, 1.0)
-    codes = jnp.round(buf / safe * lv)                  # [-L, L] floats
-    v = jnp.where(scale > 0, codes / lv * safe, 0.0)
-    enew_ref[0, :] = buf - v
-    scale_ref[0, 0] = scale
 
-    biased = jnp.where(scale > 0, codes, 0.0).astype(jnp.int32) + levels
-    pad = W * per_word - block
-    if pad:
-        # pad lanes are zero BITS (matching payloads.pack_codes), not the
-        # biased zero code -- unpack trims them before unbiasing
-        biased = jnp.concatenate([biased, jnp.zeros((pad,), jnp.int32)])
-    lanes = biased.astype(jnp.uint32)
-    acc = jnp.zeros((W,), jnp.uint32)
-    for i in range(per_word):
-        acc = acc | (lanes[i::per_word] << jnp.uint32(bits * i))
-    words_ref[0, :] = acc
+def _kernel(e_ref, d_ref, p_ref, words_ref, scale_ref, enew_ref, col_ref,
+            *, bits: int, block: int):
+    wp = words_ref.shape[-1]
+
+    def rows(sl):
+        buf, codes, scale, v = quantize_rows(e_ref[sl, :], d_ref[sl, :],
+                                             bits, block)
+        enew_ref[sl, :] = (buf - v).astype(enew_ref.dtype)
+        col_ref[sl, :] = jnp.broadcast_to(scale, (scale.shape[0],
+                                                  tiling.LANE))
+        biased = (codes + float(2 ** (bits - 1) - 1)).astype(jnp.bfloat16)
+        halves = jnp.dot(biased, p_ref[...],
+                         preferred_element_type=jnp.float32)
+        lo = halves[:, :wp].astype(jnp.int32)
+        hi = halves[:, wp:].astype(jnp.int32)
+        words_ref[sl, :] = jax.lax.bitcast_convert_type(lo | (hi << 16),
+                                                        jnp.uint32)
+    tiling.for_row_chunks(e_ref.shape[0], rows)
+    scale_ref[...] = tiling.rows_to_lanes(col_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def quantize_ef_pack(e: jnp.ndarray, delta: jnp.ndarray, bits: int,
                      interpret: bool | None = None):
-    """e, delta [nblocks, block] -> (words uint32 [nblocks, W],
-    scale f32 [nblocks, 1], e_new [nblocks, block])."""
+    """e, delta [..., nblocks, block] -> (words uint32 [..., nblocks, Wp],
+    scale f32 [..., nblocks], e_new shaped like e).
+
+    Each block's ``W`` wire words come zero-padded to ``Wp = lanes(W)``
+    (a multiple of 128), the row width the flat wire buffers store
+    (:mod:`repro.comm.flat`); ``words[..., :W]`` is the
+    :func:`repro.comm.payloads.pack_codes` word row."""
     from repro.comm.payloads import PACK_BITS, words_per_block
     if bits not in PACK_BITS:
         raise ValueError(f"bits={bits} not packable; expected {PACK_BITS}")
-    nblocks, block = e.shape
-    W = words_per_block(block, bits)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    kern = functools.partial(_kernel, bits=bits, block=block)
-    return pl.pallas_call(
-        kern,
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0)),
-                  pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, W), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nblocks, W), jnp.uint32),
-                   jax.ShapeDtypeStruct((nblocks, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, block), e.dtype)],
-        interpret=interpret,
-    )(e, delta)
+    e3, lead = tiling.as_batched_rows(e)
+    d3, _ = tiling.as_batched_rows(delta)
+    L, nblocks, block = e3.shape
+    bp = tiling.lanes(block)
+    wp = tiling.lanes(words_per_block(block, bits))
+    tr = tiling.row_tile(nblocks, block, align=tiling.LANE)
+    rows = lambda width: tiling.rows_spec(tr, width)  # noqa: E731
+    words, scale, e_new = pl.pallas_call(
+        functools.partial(_kernel, bits=bits, block=block),
+        grid=(L, pl.cdiv(nblocks, tr)),
+        in_specs=[rows(bp), rows(bp),
+                  pl.BlockSpec((bp, 2 * wp), lambda c, i: (0, 0))],
+        out_specs=[rows(wp),
+                   pl.BlockSpec((None, 1, tiling.lanes(tr)),
+                                lambda c, i: (c, 0, i)),
+                   rows(bp)],
+        out_shape=[jax.ShapeDtypeStruct((L, nblocks, wp), jnp.uint32),
+                   jax.ShapeDtypeStruct((L, 1, nblocks), jnp.float32),
+                   jax.ShapeDtypeStruct(e3.shape, e.dtype)],
+        scratch_shapes=[pltpu.VMEM((tiling.lanes(tr), tiling.LANE),
+                                   jnp.float32)],
+        interpret=tiling.interpret_mode(interpret),
+    )(e3, d3, place_values(block, bits))
+    return (words.reshape(lead + (nblocks, wp)),
+            scale.reshape(lead + (nblocks,)), e_new.reshape(e.shape))

@@ -13,15 +13,16 @@ dimension (``rows`` blocks per program, the autotuner's rows-per-program
 knob) and the client axis rides the inner grid dimension, so each output
 tile is revisited consecutively (TPU output-revisit rule) and accumulates
 in VMEM.  No atomics, no data-dependent control flow: the one-hot compare
-vectorizes on the VPU and the weighted contraction feeds the MXU-friendly
-``sum_k v[..., None] * onehot``.
+vectorizes on the VPU, one payload slot at a time over a sublane-aligned
+tile of destination blocks.  Client weights and segment ids sit in SMEM
+(:mod:`repro.kernels.tiling`).
 
 ``segment_rows`` is the companion segment-sum covering the ``scatter_rows``
-expansion ([m, D] participant rows -> [n, D] population layout): clients on
-the inner grid dimension, (population-chunk, feature-chunk) tiles outer,
-``out[seg_j] += rows_j`` as a one-hot outer product.  Duplicate segment ids
-*add* (true segment-sum semantics); the engine's unique-id scatter is the
-special case where add == set.
+expansion ([m, D] participant rows -> [n, D] population layout): each grid
+step holds all m rows of one feature chunk and one population chunk of the
+output, and adds row ``j`` where the output row id equals ``seg_j``.
+Duplicate segment ids *add* (true segment-sum semantics); the engine's
+unique-id scatter is the special case where add == set.
 
 Both kernels run in interpret mode off-TPU; the CPU hot path uses the
 tuned jnp formulations in :mod:`repro.kernels.ops` instead (see
@@ -35,66 +36,67 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
 
-def _agg_kernel(vals_ref, idx_ref, weight_ref, acc_ref, *, block: int):
+
+def _agg_kernel(weight_ref, vals_ref, idx_ref, acc_ref, *, k: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
-    v = vals_ref[0].astype(jnp.float32) * weight_ref[0]     # [rows, k]
-    ids = idx_ref[0]                                        # [rows, k]
-    rows, k = ids.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (rows, k, block), 2)
-    oh = (ids[..., None] == iota).astype(jnp.float32)       # [rows, k, block]
-    acc_ref[...] += jnp.sum(v[..., None] * oh, axis=1)
+    v = vals_ref[...].astype(jnp.float32) * weight_ref[j]   # [rows, slots]
+    ids = idx_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+    acc = acc_ref[...]
+    for t in range(k):
+        acc += jnp.where(lane == ids[:, t:t + 1], v[:, t:t + 1], 0.0)
+    acc_ref[...] = acc
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block", "rows", "interpret"))
+                   static_argnames=("block", "rows", "k", "interpret"))
 def scatter_agg(vals: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
-                block: int, rows: int = 8,
+                block: int, rows: int = 8, k: int | None = None,
                 interpret: bool | None = None) -> jnp.ndarray:
-    """vals [n, nblocks, k] + idx [n, nblocks, k] (within-block offsets in
-    [0, block)) + weight [n] -> weighted bucket sums [nblocks, block] f32.
+    """vals [n, nblocks, slots] + idx [n, nblocks, slots] (within-block
+    offsets in [0, block)) + weight [n] -> weighted bucket sums
+    [nblocks, block] f32 over the first ``k`` slots of each block (all of
+    them by default; the flat wire buffers pad their slot rows to 128).
 
     ``rows`` is the destination-blocks-per-program tile (the autotuner's
-    rows-per-program knob); ``nblocks`` is padded up to a multiple of it
-    with zero-value slots (zero values contribute nothing)."""
-    n, nblocks, k = vals.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    rows = max(1, min(rows, nblocks))
-    pad = (-nblocks) % rows
-    if pad:
-        vals = jnp.pad(vals, ((0, 0), (0, pad), (0, 0)))
-        idx = jnp.pad(idx, ((0, 0), (0, pad), (0, 0)))
-    nb_pad = nblocks + pad
-    out = pl.pallas_call(
-        functools.partial(_agg_kernel, block=block),
-        grid=(nb_pad // rows, n),
-        in_specs=[pl.BlockSpec((1, rows, k), lambda i, j: (j, i, 0)),
-                  pl.BlockSpec((1, rows, k), lambda i, j: (j, i, 0)),
-                  pl.BlockSpec((1,), lambda i, j: (j,))],
-        out_specs=pl.BlockSpec((rows, block), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb_pad, block), jnp.float32),
-        interpret=interpret,
-    )(vals, idx.astype(jnp.int32), weight.astype(jnp.float32))
-    return out[:nblocks]
+    rows-per-program knob), rounded up to a multiple of 8 sublanes; a
+    partial last tile reads undefined slots for blocks past ``nblocks``
+    and its writes there are dropped."""
+    n, nblocks, slots = vals.shape
+    k = slots if k is None else k
+    tr = tiling.row_tile(nblocks, block,
+                         cap=tiling.round_up(rows, tiling.SUBLANE))
+    kp = tiling.lanes(slots)
+    return pl.pallas_call(
+        functools.partial(_agg_kernel, k=k),
+        grid=(pl.cdiv(nblocks, tr), n),
+        in_specs=[tiling.smem_spec(),
+                  pl.BlockSpec((None, tr, kp), lambda i, j: (j, i, 0)),
+                  pl.BlockSpec((None, tr, kp), lambda i, j: (j, i, 0))],
+        out_specs=pl.BlockSpec((tr, tiling.lanes(block)), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nblocks, block), jnp.float32),
+        interpret=tiling.interpret_mode(interpret),
+    )(weight.astype(jnp.float32), vals, idx.astype(jnp.int32))
 
 
-def _seg_kernel(rows_ref, seg_ref, acc_ref, *, crows: int):
-    j = pl.program_id(2)
+def _seg_kernel(seg_ref, rows_ref, acc_ref, *, m: int):
+    crows = acc_ref.shape[0]
+    rid = jax.lax.broadcasted_iota(jnp.int32, (crows, 1), 0) \
+        + pl.program_id(0) * crows
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def add(j, acc):
+        row = rows_ref[pl.ds(j, 1), :].astype(jnp.float32)   # [1, cd]
+        return acc + jnp.where(rid == seg_ref[j], row, 0.0)
 
-    base = pl.program_id(0) * crows
-    iota = jax.lax.broadcasted_iota(jnp.int32, (crows, 1), 0) + base
-    oh = (iota == seg_ref[0]).astype(jnp.float32)           # [crows, 1]
-    acc_ref[...] += oh * rows_ref[...].astype(jnp.float32)  # [crows, cd]
+    acc_ref[...] = jax.lax.fori_loop(
+        0, m, add, jnp.zeros(acc_ref.shape, jnp.float32))
 
 
 @functools.partial(jax.jit,
@@ -104,22 +106,20 @@ def segment_rows(rows: jnp.ndarray, seg: jnp.ndarray, n: int,
                  interpret: bool | None = None) -> jnp.ndarray:
     """Segment-sum of [m, D] rows into [n, D]: ``out[i] = sum_{seg_j == i}
     rows_j`` (f32).  Out-of-range ids drop; duplicate ids add.  ``crows`` /
-    ``cd`` tile the (population, feature) axes of the output."""
+    ``cd`` tile the (population, feature) axes of the output, rounded up to
+    8 sublanes / 128 lanes; ``cd`` shrinks so the m input rows of one
+    feature chunk fit a VMEM tile."""
     m, D = rows.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    crows = max(1, min(crows, n))
-    cd = max(1, min(cd, D))
-    pad_n, pad_d = (-n) % crows, (-D) % cd
-    if pad_d:
-        rows = jnp.pad(rows, ((0, 0), (0, pad_d)))
-    out = pl.pallas_call(
-        functools.partial(_seg_kernel, crows=crows),
-        grid=((n + pad_n) // crows, (D + pad_d) // cd, m),
-        in_specs=[pl.BlockSpec((1, cd), lambda i, l, j: (j, l)),
-                  pl.BlockSpec((1,), lambda i, l, j: (j,))],
-        out_specs=pl.BlockSpec((crows, cd), lambda i, l, j: (i, l)),
-        out_shape=jax.ShapeDtypeStruct((n + pad_n, D + pad_d), jnp.float32),
-        interpret=interpret,
-    )(rows, seg.astype(jnp.int32))
-    return out[:n, :D]
+    cd = tiling.lanes(min(cd, D))
+    cd = min(cd, max(tiling.LANE, tiling.TILE_BYTES // (4 * m)
+                     // tiling.LANE * tiling.LANE))
+    crows = n if n <= crows else tiling.round_up(crows, tiling.SUBLANE)
+    return pl.pallas_call(
+        functools.partial(_seg_kernel, m=m),
+        grid=(pl.cdiv(n, crows), pl.cdiv(D, cd)),
+        in_specs=[tiling.smem_spec(),
+                  pl.BlockSpec((m, cd), lambda i, l: (0, l))],
+        out_specs=pl.BlockSpec((crows, cd), lambda i, l: (i, l)),
+        out_shape=jax.ShapeDtypeStruct((n, D), jnp.float32),
+        interpret=tiling.interpret_mode(interpret),
+    )(seg.astype(jnp.int32), rows)

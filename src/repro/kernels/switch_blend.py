@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 
 def _kernel(sigma_ref, gf_ref, gg_ref, out_ref):
     s = sigma_ref[0]
@@ -22,25 +24,22 @@ def _kernel(sigma_ref, gf_ref, gg_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def switch_blend(gf: jnp.ndarray, gg: jnp.ndarray, sigma: jnp.ndarray,
                  block: int = 4096, interpret: bool | None = None):
-    """gf, gg flat [d]; sigma scalar -> blended [d]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """gf, gg flat [d]; sigma scalar -> blended [d].  The buffers are viewed
+    as [rows, block] (block rounded up to 128 lanes, zero-padded)."""
     d = gf.shape[0]
-    block = min(block, d)
+    block = tiling.lanes(min(block, d))
     pad = (-d) % block
     gf2 = jnp.pad(gf, (0, pad)).reshape(-1, block)
     gg2 = jnp.pad(gg, (0, pad)).reshape(-1, block)
-    nblocks = gf2.shape[0]
+    nrows = gf2.shape[0]
+    tr = tiling.row_tile(nrows, block)
+    spec = pl.BlockSpec((tr, block), lambda i: (i, 0))
     out = pl.pallas_call(
         _kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),     # sigma: whole (1,) array
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, block), gf.dtype),
-        interpret=interpret,
-    )(sigma.reshape(1), gf2, gg2)
+        grid=(pl.cdiv(nrows, tr),),
+        in_specs=[tiling.smem_spec(), spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((nrows, block), gf.dtype),
+        interpret=tiling.interpret_mode(interpret),
+    )(sigma.reshape(1).astype(jnp.float32), gf2, gg2)
     return out.reshape(-1)[:d]

@@ -148,6 +148,12 @@ def get_plan(kind: str, **sig: Any) -> Plan:
     return plan
 
 
+def table() -> Dict[str, Plan]:
+    """Every plan resolved or loaded in this process, by cache key."""
+    with _lock:
+        return dict(_load())
+
+
 def put_plan(kind: str, plan: Plan, **sig: Any) -> None:
     global _dirty
     key = key_for(kind, jax.default_backend(), **sig)

@@ -80,7 +80,8 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, comm: str = "dense",
     flops_eff = max(cost["flops"], mf / chips)
     coll_eff = roofline.corrected_collective_bytes(coll, cfg.n_layers)
     terms = roofline.roofline_terms(flops_eff, cost["bytes"],
-                                    coll_eff, chips)
+                                    coll_eff, chips,
+                                    roofline.DRYRUN_DEVICE_KIND)
     terms["collective_bytes_raw"] = coll["total"]
     terms["collective_bytes_corrected"] = coll_eff
     rec.update(

@@ -1,8 +1,10 @@
 """Roofline-term derivation from compiled dry-run artifacts (TPU v5e model).
 
-  compute term    = HLO_FLOPs / (chips * PEAK_FLOPS)
-  memory term     = HLO_bytes / (chips * HBM_BW)
-  collective term = collective_bytes / (chips * LINK_BW)
+  compute term    = HLO_FLOPs / (chips * peak FLOP/s)
+  memory term     = HLO_bytes / (chips * HBM bandwidth)
+  collective term = collective_bytes / (chips * ICI link bandwidth)
+
+with the peaks of the chip looked up by its ``device_kind`` (PEAKS).
 
 collective_bytes is parsed from the compiled HLO text: we sum the operand
 sizes of every all-gather / all-reduce / reduce-scatter / all-to-all /
@@ -13,10 +15,25 @@ from __future__ import annotations
 import re
 from typing import Dict
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12      # bf16 FLOP/s
-HBM_BW = 819e9           # B/s
-LINK_BW = 50e9           # B/s per ICI link
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  A device missing
+# here is an error, never a default.
+#   "TPU v5 lite" (TPU v5e) -- Google Cloud documentation, "TPU v5e":
+#   197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+#   interconnect (4 links, so 50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+# the chip the compile-only dry runs (launch/dryrun.py) model
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -128,7 +145,7 @@ def memory_summary(compiled) -> Dict[str, float]:
 
 
 def roofline_terms(flops: float, hbm_bytes: float, coll_bytes: float,
-                   chips: int) -> Dict[str, float]:
+                   chips: int, device_kind: str) -> Dict[str, float]:
     """All three terms in seconds + the dominant bottleneck.
 
     NOTE: cost_analysis() and as_text() describe the SPMD-*partitioned*
@@ -137,9 +154,10 @@ def roofline_terms(flops: float, hbm_bytes: float, coll_bytes: float,
     "/(chips * peak)" normalization applies to whole-mesh totals; with
     per-device numbers the chips factor is already folded in.
     """
-    t_compute = flops / PEAK_FLOPS
-    t_memory = hbm_bytes / HBM_BW
-    t_coll = coll_bytes / LINK_BW
+    peak = peaks(device_kind)
+    t_compute = flops / peak["flops"]
+    t_memory = hbm_bytes / peak["hbm_bw"]
+    t_coll = coll_bytes / peak["link_bw"]
     dom = max(("compute", t_compute), ("memory", t_memory),
               ("collective", t_coll), key=lambda kv: kv[1])[0]
     return {"compute_s": t_compute, "memory_s": t_memory,

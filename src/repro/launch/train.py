@@ -1,10 +1,16 @@
 """Training launcher: run FedSGM rounds for any assigned architecture.
 
-On real hardware this drives the production mesh; on CPU it runs the reduced
-config (``--reduced``, default when only one device is present).
+The model runs at its published widths unless ``--reduced`` asks for the
+cut-down smoke-test variant (``configs.reduce_model``); the device count
+never chooses it.  On a CPU:
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --reduced \
         --rounds 20 --seq 64 --batch 2
+
+``main(argv)`` is the same entry point in-process (``chip_smoke.py`` calls
+it).  The sink's opening record names the platform, device kind and device
+count the run used, and JAX's persistent compilation cache is configured
+before the first compile (:mod:`repro.launch.compile_cache`).
 
 Observability (DESIGN.md §Obs): ``--obs`` turns on the in-jit telemetry
 bus, ``--sink {stdout,jsonl,memory}`` selects where per-round records go
@@ -16,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -26,6 +33,8 @@ from repro.configs.base import (AsyncConfig, CompressorConfig, FedConfig,
                                 SwitchConfig)
 from repro.core import fedsgm
 from repro.data import synthetic
+from repro.engine import rounds
+from repro.launch import compile_cache
 from repro.models import build
 from repro.obs import log as obs_log
 from repro.obs import sinks as obs_sinks
@@ -68,7 +77,7 @@ def _run_wire(args, cfg):
                       else {}))
     sink.open(meta={"arch": cfg.name, "rounds": args.rounds,
                     "comm": args.comm, "strategy": args.strategy,
-                    "wire_workers": args.wire})
+                    "wire_workers": args.wire, **device_meta()})
     resume = bool(args.ckpt_dir
                   and checkpoint.latest_round(args.ckpt_dir) is not None)
     faults = None
@@ -101,10 +110,28 @@ def _run_wire(args, cfg):
     return state
 
 
-def main():
+class TrainRun(NamedTuple):
+    """What :func:`main` ran: the final state and, on the host-batch path,
+    the jitted round program with one batch of its input shape (so a caller
+    can inspect the compiled program)."""
+    state: object
+    step: object = None
+    batch: object = None
+
+
+def device_meta() -> dict:
+    """What the run executes on, as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
-    ap.add_argument("--reduced", action="store_true", default=None)
+    ap.add_argument("--reduced", action="store_true",
+                    help="cut-down smoke-test widths (configs.reduce_model)"
+                         " instead of the published config")
     ap.add_argument("--rounds", type=int, default=20)
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--participating", type=int, default=0)
@@ -212,18 +239,17 @@ def main():
     ap.add_argument("--max-respawns", type=int, default=2,
                     help="per-worker respawn budget for the wire "
                          "supervisor (with --wire-heartbeat; default 2)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     obs_log.set_level("warning" if args.quiet else args.log_level)
+    compile_cache.configure()
     profile = obs_trace.ProfileWindow(args.profile)
 
-    reduced = args.reduced
-    if reduced is None:
-        reduced = jax.device_count() == 1
-    cfg = configs.get_reduced(args.arch) if reduced else configs.get_config(args.arch)
+    cfg = (configs.get_reduced(args.arch) if args.reduced
+           else configs.get_config(args.arch))
 
     if args.wire:
-        return _run_wire(args, cfg)
+        return TrainRun(_run_wire(args, cfg))
 
     if args.multi_pod:
         from repro.launch.mesh import make_production_mesh
@@ -268,7 +294,7 @@ def main():
                     "comm": args.comm, "strategy": args.strategy,
                     "participation": args.participation,
                     "async_buffer": args.async_buffer, "obs": args.obs,
-                    "start_round": start_round})
+                    "start_round": start_round, **device_meta()})
 
     t0 = time.time()
     if args.fleet:
@@ -320,7 +346,7 @@ def main():
                     async_rounds.buffer_wire(buf, state.w, fed))
         profile.close()
         sink.close()
-        return
+        return TrainRun(state)
 
     def batch_fn(t, k):
         toks, mask = synthetic.client_token_batches(
@@ -339,6 +365,7 @@ def main():
         astep = jax.jit(lambda s, b, batch: async_rounds.async_round_step(
             s, b, batch, loss_pair, fed))
 
+    step = None if args.async_buffer else rounds.round_program(loss_pair, fed)
     for chunk in range(max(args.rounds // 10, 1)):
         profile.tick(start_round + 10 * chunk)
         if args.async_buffer:
@@ -352,7 +379,7 @@ def main():
                 lambda *xs: np.stack(xs), *per_round)
         else:
             state, hist = fedsgm.run_rounds(state, batch_fn, loss_pair,
-                                            fed, T=10)
+                                            fed, T=10, step=step)
         done = start_round + 10 * (chunk + 1)
         for rec in obs_sinks.rows(
                 hist, start_round=done - 10,
@@ -369,6 +396,7 @@ def main():
                     async_rounds.buffer_wire(buf, state.w, fed))
     profile.close()
     sink.close()
+    return TrainRun(state, step, batch_fn(0, jax.random.PRNGKey(0)))
 
 
 if __name__ == "__main__":

@@ -13,7 +13,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import partition
@@ -61,10 +60,10 @@ def flash_decode_attend(q, k_cache, v_cache, kv_valid, mesh=None,
         return o_glob / jnp.maximum(l_glob, 1e-30)[..., None]
 
     spec_kv = P(None, axis, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(P(), spec_kv, spec_kv, P(axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(qg, k_cache, v_cache, kv_valid)
     return out.reshape(B, 1, H * hd).astype(q.dtype)

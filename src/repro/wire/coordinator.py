@@ -988,6 +988,13 @@ def wire_drive(fed: FedConfig, T: int, workers: int = 2, *,
     if spawn not in ("process", "thread"):
         raise ValueError(f"spawn must be 'process' or 'thread', "
                          f"got {spawn!r}")
+    if spawn == "process" and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "wire_drive: a TPU chip belongs to one process at a time, and "
+            "this coordinator already holds it -- worker processes started "
+            "here could not reach it.  The wire runtime is a CPU "
+            "federation harness: run it with JAX_PLATFORMS=cpu, or use "
+            "spawn='thread' to keep every worker in this process")
     if proc_chaos is not None:
         if faults is None:
             raise ValueError("proc_chaos kills real workers; arm the "

@@ -39,11 +39,18 @@ ENTRY %main (a: f32[128,64]) -> f32[128,64] {
 
 def test_roofline_terms():
     from repro.launch import roofline
-    t = roofline.roofline_terms(197e12, 0.0, 0.0, 256)
+    t = roofline.roofline_terms(197e12, 0.0, 0.0, 256, "TPU v5 lite")
     assert abs(t["compute_s"] - 1.0) < 1e-9
     assert t["dominant"] == "compute"
-    t = roofline.roofline_terms(0.0, 819e9, 50e9 * 2, 256)
+    t = roofline.roofline_terms(0.0, 819e9, 50e9 * 2, 256, "TPU v5 lite")
     assert t["dominant"] == "collective"
+
+
+def test_roofline_unknown_device_kind_raises():
+    import pytest
+    from repro.launch import roofline
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.roofline_terms(1.0, 1.0, 1.0, 1, "cpu")
 
 
 def test_model_flops():

@@ -83,11 +83,16 @@ class TestPackedWords:
            block=st.sampled_from([5, 12, 32, 33]), seed=st.integers(0, 999))
     def test_fused_kernel_words_match_jnp_pack(self, bits, block, seed):
         """quantize_ef_pack emits bit-for-bit the words of quantize +
-        payloads.pack_codes, and the residual of the unfused EF step."""
+        payloads.pack_codes (word rows zero-padded to 128 lanes), and the
+        residual of the unfused EF step."""
         key = jax.random.PRNGKey(seed)
         e = jax.random.normal(key, (4, block))
         d = jax.random.normal(jax.random.fold_in(key, 1), (4, block))
         words, scale, e_new = quantize_ef_pack(e, d, bits)
+        W = payloads.words_per_block(block, bits)
+        assert words.shape == (4, -(-W // 128) * 128)
+        assert not np.asarray(words[:, W:]).any()
+        words, scale = words[:, :W], scale[:, None]
         buf = e + d
         sc = jnp.max(jnp.abs(buf), axis=-1, keepdims=True)
         L = float(2 ** (bits - 1) - 1)
@@ -205,6 +210,89 @@ class TestFlatTransportParity:
                 "randk": CompressorConfig(kind="randk", ratio=0.25, block=8),
                 "quant": CompressorConfig(kind="quant", bits=4, block=8),
                 "natural": CompressorConfig(kind="natural")}[kind]
+
+    def test_row_loops_match_gather_and_scatter(self, monkeypatch):
+        """Long rows move by dynamic-slice loops (ROW_LOOP_ELEMS); they
+        give XLA's row gather and scatter exactly."""
+        key = jax.random.PRNGKey(0)
+        x = jax.random.normal(key, (6, 10))
+        rows = jax.random.normal(jax.random.fold_in(key, 1), (3, 10))
+        idx = jnp.asarray([4, 1, 3], jnp.int32)
+        want = (jnp.take(x, idx, axis=0), x.at[idx].set(rows))
+        monkeypatch.setattr(transports, "ROW_LOOP_ELEMS", 0)
+        got = jax.jit(lambda x_, r_: (transports.take_rows(x_, idx),
+                                      transports.put_rows(x_, idx, r_)))(
+            x, rows)
+        for a, b in zip(want, got):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("kind,backend", [("topk", "pallas"),
+                                              ("quant", "pallas"),
+                                              ("randk", "packed")])
+    def test_gathered_encode_one_participant_at_a_time(self, kind, backend,
+                                                       monkeypatch):
+        """The row-at-a-time gathered encode (taken above ROW_LOOP_BYTES of
+        deltas) gives the vectorized encode's messages and residuals,
+        repeated ids (a padded realized cohort) included."""
+        key = jax.random.PRNGKey(0)
+        spec = flat.spec_of(_mlp_tree(key))
+        ft = flat.FlatTransport(
+            transports.get_transport(self._compressor(kind), backend), spec)
+        n = 6
+        e = jax.random.normal(jax.random.fold_in(key, 1), (n, spec.d))
+        idx = jnp.asarray([1, 3, 4, 1], jnp.int32)
+        deltas = jax.random.normal(jax.random.fold_in(key, 2), (4, spec.d))
+        deltas = deltas.at[3].set(deltas[0])      # the repeat's own row
+        mask = jnp.zeros((n,)).at[idx].set(1.0)
+        kk = jax.random.PRNGKey(9)
+        enc = jax.jit(lambda e_, d_: ft.encode_gathered(e_, d_, idx, mask,
+                                                        key=kk))
+        want = enc(e, deltas)
+        monkeypatch.setattr(flat, "ROW_LOOP_BYTES", 0)
+        got = jax.jit(lambda e_, d_: ft.encode_gathered(e_, d_, idx, mask,
+                                                        key=kk))(e, deltas)
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(got)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("kind,backend", [("topk", "packed"),
+                                              ("topk", "pallas"),
+                                              ("randk", "packed"),
+                                              ("quant", "packed"),
+                                              ("quant", "pallas")])
+    def test_lane_padded_payload_rows_transmit_alike(self, kind, backend,
+                                                     monkeypatch):
+        """Large runs store each block's payload row padded to 128 lanes
+        (PAD_ROWS_FROM); the padded layout transmits the same aggregate
+        and residuals, and counts the same wire bytes."""
+        key = jax.random.PRNGKey(0)
+        spec = flat.spec_of(_mlp_tree(key))
+        cfg = self._compressor(kind)
+        t = transports.get_transport(cfg, backend)
+        n = 6
+        deltas = jax.random.normal(jax.random.fold_in(key, 3), (n, spec.d))
+        e = jax.random.normal(jax.random.fold_in(key, 4), (n, spec.d))
+        mask = jnp.asarray([1, 0, 1, 1, 0, 1], jnp.float32)
+        kk = jax.random.PRNGKey(9)
+
+        def run():
+            flat._LAYOUT_CACHE.clear()
+            ft = flat.FlatTransport(t, spec)
+            out = jax.jit(lambda d_: ft.transmit(e, d_, mask, 4, key=kk))(
+                deltas)
+            return out, ft.wire_bytes(), ft.codec.layout
+
+        (v0, e0), bytes0, lay0 = run()
+        monkeypatch.setattr(flat, "PAD_ROWS_FROM", 0)
+        (v1, e1), bytes1, lay1 = run()
+        flat._LAYOUT_CACHE.clear()
+        assert all(r.ks % 128 == 0 and r.Ws % 128 == 0 for r in lay1.runs)
+        assert lay1.K_total > lay0.K_total
+        assert bytes1 == bytes0
+        np.testing.assert_array_equal(np.asarray(e0), np.asarray(e1))
+        np.testing.assert_allclose(np.asarray(v0), np.asarray(v1),
+                                   rtol=1e-6, atol=1e-7)
 
     def test_select_payload_codes_round_trip_exactly(self):
         """Flat top-k payloads carry the exact values/offsets of the tree
