@@ -45,7 +45,7 @@ from benchmarks.wire_bench import _cfg, _oracle
 DEFAULT_OUT = "BENCH_faults.json"
 
 # effectively unbounded: the soak measures sustained recovery, not the
-# budget cliff (the budget path is pinned in tests/test_faults.py)
+# budget cliff (the budget path is pinned in tests/test_wire_faults.py)
 SOAK_RESPAWNS = 10 ** 6
 
 

@@ -126,11 +126,12 @@ def flatten(spec: FlatSpec, tree) -> jnp.ndarray:
             f"flatten: tree has {len(leaves)} leaves but the FlatSpec "
             f"records {len(spec.leaves)} -- not the spec'd structure "
             "(payload pytrees cannot be flattened as dense buffers)")
-    out = []
-    for l, ls in zip(leaves, spec.leaves):
-        lead = l.shape[:l.ndim - len(ls.shape)]
-        out.append(l.astype(spec.dtype).reshape(lead + (ls.size,)))
-    return jnp.concatenate(out, axis=-1) if len(out) > 1 else out[0]
+    with obs_trace.stage("flat.flatten"):
+        out = []
+        for l, ls in zip(leaves, spec.leaves):
+            lead = l.shape[:l.ndim - len(ls.shape)]
+            out.append(l.astype(spec.dtype).reshape(lead + (ls.size,)))
+        return jnp.concatenate(out, axis=-1) if len(out) > 1 else out[0]
 
 
 def unflatten(spec: FlatSpec, flat: jnp.ndarray):
@@ -138,9 +139,10 @@ def unflatten(spec: FlatSpec, flat: jnp.ndarray):
     Slices + reshapes (a dtype cast only when the tree mixes dtypes)."""
     lead = flat.shape[:-1]
     leaves = []
-    for ls in spec.leaves:
-        part = flat[..., ls.offset:ls.offset + ls.size]
-        leaves.append(part.reshape(lead + ls.shape).astype(ls.dtype))
+    with obs_trace.stage("flat.unflatten"):
+        for ls in spec.leaves:
+            part = flat[..., ls.offset:ls.offset + ls.size]
+            leaves.append(part.reshape(lead + ls.shape).astype(ls.dtype))
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
 
 
@@ -282,8 +284,9 @@ def wire_layout(spec: FlatSpec, cfg: CompressorConfig) -> WireLayout:
 def _run_view(flat: jnp.ndarray, r: RunSpec) -> jnp.ndarray:
     """[*lead, span] slice reshaped to [*lead, nblocks, block]."""
     lead = flat.shape[:-1]
-    return flat[..., r.offset:r.offset + r.span].reshape(
-        lead + (r.nblocks, r.block))
+    with obs_trace.stage("comm.relayout"):
+        return flat[..., r.offset:r.offset + r.span].reshape(
+            lead + (r.nblocks, r.block))
 
 
 def _rows(payload: jnp.ndarray, off: int, nblocks: int,
@@ -291,21 +294,26 @@ def _rows(payload: jnp.ndarray, off: int, nblocks: int,
     """A run's stored payload rows: [*lead, nblocks * width] slice at
     ``off`` viewed as [*lead, nblocks, width]."""
     lead = payload.shape[:-1]
-    return payload[..., off:off + nblocks * width].reshape(
-        lead + (nblocks, width))
+    with obs_trace.stage("comm.relayout"):
+        return payload[..., off:off + nblocks * width].reshape(
+            lead + (nblocks, width))
 
 
 def _pad_rows(x: jnp.ndarray, width: int) -> jnp.ndarray:
     """Zero-pad the last axis of [*lead, nblocks, w] payload rows to the
     stored ``width`` and flatten the run to [*lead, nblocks * width]."""
-    if x.shape[-1] < width:
-        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1)
-                    + [(0, width - x.shape[-1])])
-    return x.reshape(x.shape[:-2] + (x.shape[-2] * width,))
+    with obs_trace.stage("comm.relayout"):
+        if x.shape[-1] < width:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                        + [(0, width - x.shape[-1])])
+        return x.reshape(x.shape[:-2] + (x.shape[-2] * width,))
 
 
 def _cat(xs):
-    return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=-1)
+    if len(xs) == 1:
+        return xs[0]
+    with obs_trace.stage("comm.relayout"):
+        return jnp.concatenate(xs, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -334,8 +342,9 @@ class _SelectCodec:
                 from repro.kernels.topk_block import block_topk
                 # slot rows come padded to 128 lanes; a small run stores k
                 vals, idx = block_topk(blocks, r.k)
-                vals = vals[..., :r.ks]
-                idx = idx[..., :r.ks].astype(INDEX_DTYPE)
+                with obs_trace.stage("comm.relayout"):
+                    vals = vals[..., :r.ks]
+                    idx = idx[..., :r.ks].astype(INDEX_DTYPE)
             else:
                 vals, idx = payloads.select_topk_blocks(blocks, r.k,
                                                         r.sort_free)
@@ -370,7 +379,8 @@ class _SelectCodec:
             vals = _rows(p.values, r.koff, r.nblocks, r.ks)
             idx = _rows(p.indices, r.koff, r.nblocks, r.ks)
             acc = ops.scatter_agg(vals, idx, weights, block=r.block, k=r.k)
-            outs.append(acc.reshape(r.span))
+            with obs_trace.stage("comm.relayout"):
+                outs.append(acc.reshape(r.span))
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
         return out.astype(self.spec.dtype) / m
 
@@ -447,7 +457,8 @@ class _QuantCodec:
             scale = q.scale[:, r.boff:r.boff + r.nblocks]
             acc = ops.quant_agg(words, scale, weights, self.cfg.bits,
                                 r.block, plan=plan)
-            outs.append(acc.reshape(r.span))
+            with obs_trace.stage("comm.relayout"):
+                outs.append(acc.reshape(r.span))
         out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
         return out.astype(self.spec.dtype) / m
 
@@ -475,9 +486,11 @@ class _QuantPallasCodec(_QuantCodec):
             words, scale, e_new = quantize_ef_pack(
                 _run_view(e, r), _run_view(deltas, r), self.cfg.bits)
             # word rows come padded to 128 lanes; a small run stores W
-            ws.append(words[..., :r.Ws].reshape(lead + (r.nblocks * r.Ws,)))
+            with obs_trace.stage("comm.relayout"):
+                ws.append(words[..., :r.Ws].reshape(
+                    lead + (r.nblocks * r.Ws,)))
+                es.append(e_new.reshape(lead + (r.span,)))
             ss.append(scale)
-            es.append(e_new.reshape(lead + (r.span,)))
         return FlatQuant(_cat(ws), _cat(ss)), _cat(es)
 
     def pack(self, buf: jnp.ndarray, key=None) -> FlatQuant:
@@ -684,7 +697,8 @@ class FlatTransport:
         m = deltas.shape[0]
 
         def row(x, j):
-            return jax.lax.dynamic_slice_in_dim(x, j, 1, axis=0)
+            with obs_trace.stage("comm.rows"):
+                return jax.lax.dynamic_slice_in_dim(x, j, 1, axis=0)
 
         def encode_row(e_j, d_j, k_j):
             return self._ef_clients(e_j, d_j, key, keys=k_j)
@@ -704,11 +718,12 @@ class FlatTransport:
             msg_j = tree_map(lambda M, x: jnp.where(dup, row(M, first), x),
                              msgs, msg_j)
             e_new = jnp.where(dup, e_j, e_new.astype(e.dtype))
-            msgs = tree_map(
-                lambda M, x: jax.lax.dynamic_update_slice_in_dim(M, x, j, 0),
-                msgs, msg_j)
-            return msgs, jax.lax.dynamic_update_slice_in_dim(e, e_new,
-                                                             idx[j], 0)
+            with obs_trace.stage("comm.rows"):
+                msgs = tree_map(
+                    lambda M, x: jax.lax.dynamic_update_slice_in_dim(
+                        M, x, j, 0), msgs, msg_j)
+                return msgs, jax.lax.dynamic_update_slice_in_dim(
+                    e, e_new, idx[j], 0)
         return jax.lax.fori_loop(0, m, body, (msgs0, e))
 
     def reduce_single(self, msgs, weights, m, like=None) -> jnp.ndarray:

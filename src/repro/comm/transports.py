@@ -35,6 +35,7 @@ from repro.configs.base import CompressorConfig
 from repro.comm import payloads
 from repro.comm.payloads import (PackedLeaf, QuantPayload, block_geometry,
                                  choose_block)
+from repro.obs import trace as obs_trace
 
 tree_map = jax.tree_util.tree_map
 
@@ -109,14 +110,15 @@ ROW_LOOP_ELEMS = 1 << 20
 def take_rows(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """``x[idx]`` for a stacked [n, ...] array and in-range row ids; long
     rows move one dynamic row slice per id (:data:`ROW_LOOP_ELEMS`)."""
-    if x[0].size < ROW_LOOP_ELEMS:
-        return jnp.take(x, idx, axis=0)
+    with obs_trace.stage("comm.rows"):
+        if x[0].size < ROW_LOOP_ELEMS:
+            return jnp.take(x, idx, axis=0)
 
-    def body(j, out):
-        row = jax.lax.dynamic_slice_in_dim(x, idx[j], 1, axis=0)
-        return jax.lax.dynamic_update_slice_in_dim(out, row, j, axis=0)
-    out = jnp.zeros((idx.shape[0],) + x.shape[1:], x.dtype)
-    return jax.lax.fori_loop(0, idx.shape[0], body, out)
+        def body(j, out):
+            row = jax.lax.dynamic_slice_in_dim(x, idx[j], 1, axis=0)
+            return jax.lax.dynamic_update_slice_in_dim(out, row, j, axis=0)
+        out = jnp.zeros((idx.shape[0],) + x.shape[1:], x.dtype)
+        return jax.lax.fori_loop(0, idx.shape[0], body, out)
 
 
 def put_rows(x: jnp.ndarray, idx: jnp.ndarray,
@@ -124,14 +126,15 @@ def put_rows(x: jnp.ndarray, idx: jnp.ndarray,
     """``x.at[idx].set(rows)`` for in-range row ids (a repeated id writes
     one of its rows); long rows land one dynamic row update per id
     (:data:`ROW_LOOP_ELEMS`)."""
-    if x[0].size < ROW_LOOP_ELEMS:
-        return x.at[idx].set(rows.astype(x.dtype))
+    with obs_trace.stage("comm.rows"):
+        if x[0].size < ROW_LOOP_ELEMS:
+            return x.at[idx].set(rows.astype(x.dtype))
 
-    def body(j, out):
-        row = jax.lax.dynamic_slice_in_dim(rows, j, 1, axis=0)
-        return jax.lax.dynamic_update_slice_in_dim(out, row.astype(out.dtype),
-                                                   idx[j], axis=0)
-    return jax.lax.fori_loop(0, idx.shape[0], body, x)
+        def body(j, out):
+            row = jax.lax.dynamic_slice_in_dim(rows, j, 1, axis=0)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, row.astype(out.dtype), idx[j], axis=0)
+        return jax.lax.fori_loop(0, idx.shape[0], body, x)
 
 
 def scatter_rows(tree, idx, n: int):

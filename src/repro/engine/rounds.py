@@ -183,7 +183,9 @@ def eval_clients(w, batches, loss_pair: Callable, cfg: FedConfig):
 def _sgd_scan(w0, batch, grad_fn, eta, steps: int):
     """``steps`` local SGD steps on the flat buffer (one client's batch)."""
     def body(w, _):
-        return w - eta * grad_fn(w, batch), None
+        grad = grad_fn(w, batch)
+        with obs_trace.stage("local.update"):
+            return w - eta * grad, None
     w_E, _ = jax.lax.scan(body, w0, None, length=steps)
     return w_E
 
@@ -200,9 +202,12 @@ def local_deltas(wf, spec, strat, sigma, local_b, loss_pair: Callable,
     obj = strat.local_objective(loss_pair, sigma, cfg)
     grad_fn = jax.grad(
         lambda wfj, batch: obj(flat.unflatten(spec, wfj), batch))
-    return participation.client_vmap(
-        lambda b: (wf - _sgd_scan(wf, b, grad_fn, eta, E)) / eta,
-        cfg.client_chunk)(local_b)
+
+    def delta(b):
+        w_E = _sgd_scan(wf, b, grad_fn, eta, E)
+        with obs_trace.stage("local.update"):
+            return (wf - w_E) / eta
+    return participation.client_vmap(delta, cfg.client_chunk)(local_b)
 
 
 def compute_round(state: FedState, wf, spec, batches, fleet, part, strat,
@@ -268,7 +273,8 @@ def compute_round(state: FedState, wf, spec, batches, fleet, part, strat,
                 lambda fg: strat.blend_values(fg[0], fg[1], sigma, cfg)))
             df, dg = cots((f_ev, g_ev))
             (dW,) = pull((df, dg))
-            W_E = W0 - eta * dW
+            with obs_trace.stage("local.update"):
+                W_E = W0 - eta * dW
             if E > 1:
                 obj = strat.local_objective(loss_pair, sigma, cfg)
                 grad_fn = jax.grad(
@@ -276,7 +282,8 @@ def compute_round(state: FedState, wf, spec, batches, fleet, part, strat,
                 W_E = participation.client_vmap(
                     lambda w1, b: _sgd_scan(w1, b, grad_fn, eta, E - 1),
                     cfg.client_chunk)(W_E, local_b)
-            deltas = (wf - W_E) / eta
+            with obs_trace.stage("local.update"):
+                deltas = (wf - W_E) / eta
         deltas = partition.constrain_flat(
             partition.constrain_leading(deltas, "client"))
         return (batches, pre_gathered, f_part, g_hat, g_full, f_full,
@@ -320,17 +327,20 @@ def finish_round(state: FedState, strat, cfg: FedConfig, spec, wf, part,
         x_new = strat.server_update(xf, v_bar, cfg, spec=spec)
     with obs_trace.stage("round.downlink"):
         w_new_f = downlink.broadcast(wf, x_new, key=k_down)
-    w_new = flat.unflatten(spec, partition.constrain_flat(w_new_f))
-    x_keep = flat.unflatten(spec, x_new) if downlink.tracks_center else None
+    with obs_trace.stage("engine.bookkeeping"):
+        w_new = flat.unflatten(spec, partition.constrain_flat(w_new_f))
+        x_keep = flat.unflatten(spec, x_new) if downlink.tracks_center \
+            else None
 
-    alpha = strat.iterate_weight(g_hat, cfg)
-    wbar_sum = (tree_axpy(alpha, state.w, state.wbar_sum)
-                if state.wbar_sum is not None else None)
+        alpha = strat.iterate_weight(g_hat, cfg)
+        wbar_sum = (tree_axpy(alpha, state.w, state.wbar_sum)
+                    if state.wbar_sum is not None else None)
 
     # delta_norm pays a full extra [n, d] reduction: gate it when the run
     # discards per-round diagnostics (cfg.lean_metrics) -- bit-parity when on
-    delta_norm = jnp.zeros(()) if cfg.lean_metrics else \
-        flat.tree_norm(spec, participation.aggregate(part, deltas))
+    with obs_trace.stage("engine.delta_norm"):
+        delta_norm = jnp.zeros(()) if cfg.lean_metrics else \
+            flat.tree_norm(spec, participation.aggregate(part, deltas))
     # the telemetry bus (repro.obs): pure reductions over buffers this tail
     # already holds; None when disabled -- an empty subtree, no new leaves
     telemetry = None
@@ -339,18 +349,20 @@ def finish_round(state: FedState, strat, cfg: FedConfig, spec, wf, part,
             telemetry = obs_bus.round_telemetry(
                 cfg, deltas, e_up, x_new, wf, w_new_f, g_hat, sigma,
                 uplink, downlink, slot_stats)
-    metrics = RoundMetrics(
-        f=f_part, g_hat=g_hat, g_full=g_full, sigma=sigma,
-        feasible=(g_hat <= cfg.switch.eps).astype(jnp.float32),
-        delta_norm=delta_norm,
-        up_bytes=jnp.asarray(float(uplink.wire_bytes()), jnp.float32),
-        down_bytes=jnp.asarray(float(downlink.wire_bytes()), jnp.float32),
-        f_full=f_full, telemetry=telemetry)
+    with obs_trace.stage("engine.bookkeeping"):
+        metrics = RoundMetrics(
+            f=f_part, g_hat=g_hat, g_full=g_full, sigma=sigma,
+            feasible=(g_hat <= cfg.switch.eps).astype(jnp.float32),
+            delta_norm=delta_norm,
+            up_bytes=jnp.asarray(float(uplink.wire_bytes()), jnp.float32),
+            down_bytes=jnp.asarray(float(downlink.wire_bytes()),
+                                   jnp.float32),
+            f_full=f_full, telemetry=telemetry)
 
-    new_state = FedState(
-        w=w_new, x=x_keep, e_up=e_up,
-        wbar_sum=wbar_sum, wbar_weight=state.wbar_weight + alpha,
-        t=state.t + 1, key=key, sampler=samp_state)
+        new_state = FedState(
+            w=w_new, x=x_keep, e_up=e_up,
+            wbar_sum=wbar_sum, wbar_weight=state.wbar_weight + alpha,
+            t=state.t + 1, key=key, sampler=samp_state)
     return new_state, metrics
 
 

@@ -58,6 +58,7 @@ def quantize_ef(e: jnp.ndarray, delta: jnp.ndarray, bits: int,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct(e3.shape, e.dtype),
                    jax.ShapeDtypeStruct(e3.shape, e.dtype)],
+        name="quantize_ef",
         interpret=tiling.interpret_mode(interpret),
     )(e3, d3)
     return v.reshape(e.shape), e_new.reshape(e.shape)

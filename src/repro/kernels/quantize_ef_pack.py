@@ -108,6 +108,7 @@ def quantize_ef_pack(e: jnp.ndarray, delta: jnp.ndarray, bits: int,
                    jax.ShapeDtypeStruct(e3.shape, e.dtype)],
         scratch_shapes=[pltpu.VMEM((tiling.lanes(tr), tiling.LANE),
                                    jnp.float32)],
+        name="quantize_ef_pack",
         interpret=tiling.interpret_mode(interpret),
     )(e3, d3, place_values(block, bits))
     return (words.reshape(lead + (nblocks, wp)),

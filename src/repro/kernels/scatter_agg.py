@@ -82,6 +82,7 @@ def scatter_agg(vals: jnp.ndarray, idx: jnp.ndarray, weight: jnp.ndarray,
                   pl.BlockSpec((None, tr, kp), lambda i, j: (j, i, 0))],
         out_specs=pl.BlockSpec((tr, tiling.lanes(block)), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, block), jnp.float32),
+        name="scatter_agg",
         interpret=tiling.interpret_mode(interpret),
     )(weight.astype(jnp.float32), vals, idx.astype(jnp.int32))
 
@@ -121,5 +122,6 @@ def segment_rows(rows: jnp.ndarray, seg: jnp.ndarray, n: int,
                   pl.BlockSpec((m, cd), lambda i, l: (0, l))],
         out_specs=pl.BlockSpec((crows, cd), lambda i, l: (i, l)),
         out_shape=jax.ShapeDtypeStruct((n, D), jnp.float32),
+        name="segment_rows",
         interpret=tiling.interpret_mode(interpret),
     )(seg.astype(jnp.int32), rows)

@@ -40,6 +40,7 @@ def switch_blend(gf: jnp.ndarray, gg: jnp.ndarray, sigma: jnp.ndarray,
         in_specs=[tiling.smem_spec(), spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((nrows, block), gf.dtype),
+        name="switch_blend",
         interpret=tiling.interpret_mode(interpret),
     )(sigma.reshape(1).astype(jnp.float32), gf2, gg2)
     return out.reshape(-1)[:d]

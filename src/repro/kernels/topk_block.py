@@ -64,6 +64,7 @@ def block_topk(x: jnp.ndarray, k: int, interpret: bool | None = None):
         out_specs=[out, out],
         out_shape=[jax.ShapeDtypeStruct((L, nblocks, kp), x.dtype),
                    jax.ShapeDtypeStruct((L, nblocks, kp), jnp.int32)],
+        name="block_topk",
         interpret=tiling.interpret_mode(interpret),
     )(x3)
     return (vals.reshape(lead + (nblocks, kp)),

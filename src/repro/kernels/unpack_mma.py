@@ -100,6 +100,7 @@ def unpack_mma(words: jnp.ndarray, scale: jnp.ndarray, weight: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((nblocks, block), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tiling.lanes(tr), tiling.LANE),
                                    jnp.float32)],
+        name="unpack_mma",
         interpret=tiling.interpret_mode(interpret),
     )(weight.astype(jnp.float32), words,
       scale.astype(jnp.float32).reshape(n, 1, nblocks),

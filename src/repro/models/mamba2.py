@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import common
+from repro.obs import trace as obs_trace
 from repro.sharding.partition import shard_act
 
 
@@ -170,19 +171,34 @@ def _mixer(lp, cfg: ModelConfig, x, conv_cache=None, ssm_state=None,
     return y @ lp["out_proj"], new_conv, S_new
 
 
+def _embed(params, tokens):
+    with obs_trace.stage("model.embed"):
+        return params["embed"][tokens]
+
+
 def _logits(params, cfg, h):
-    h = common.rms_norm(h, params["ln_f"], cfg.norm_eps)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return shard_act(h @ w, "batch", None, "vocab")
+    with obs_trace.stage("model.head"):
+        h = common.rms_norm(h, params["ln_f"], cfg.norm_eps)
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return shard_act(h @ w, "batch", None, "vocab")
+
+
+def _block(lp, cfg: ModelConfig, h, **kw):
+    """One layer: the pre-norm, the whole SSD mixer (in-projection, causal
+    conv, ``ssd``, gate and out-projection) and the residual add.  Returns
+    (h, new_conv_cache, new_ssm_state)."""
+    with obs_trace.stage("model.mixer"):
+        y, conv, ssm_state = _mixer(
+            lp, cfg, common.rms_norm(h, lp["ln"], cfg.norm_eps), **kw)
+        return h + y, conv, ssm_state
 
 
 def forward(params, cfg: ModelConfig, tokens):
-    h = params["embed"][tokens]
-    h = shard_act(h, "batch", None, None)
+    h = shard_act(_embed(params, tokens), "batch", None, None)
 
     def body(h, lp):
-        y, _, _ = _mixer(lp, cfg, common.rms_norm(h, lp["ln"], cfg.norm_eps))
-        return h + y, None
+        h, _, _ = _block(lp, cfg, h)
+        return h, None
     body_fn = jax.checkpoint(body) if cfg.remat else body
     h, _ = jax.lax.scan(body_fn, h, params["layers"])
     return _logits(params, cfg, h)
@@ -202,24 +218,22 @@ def init_decode_cache(cfg: ModelConfig, batch: int, cache_len: int, params=None)
 
 
 def prefill(params, cfg: ModelConfig, tokens, cache_len: int):
-    h = params["embed"][tokens]
+    h = _embed(params, tokens)
 
     def body(h, lp):
-        y, conv, ssm_state = _mixer(
-            lp, cfg, common.rms_norm(h, lp["ln"], cfg.norm_eps))
-        return h + y, (conv, ssm_state)
+        h, conv, ssm_state = _block(lp, cfg, h)
+        return h, (conv, ssm_state)
     h, (convs, ssms) = jax.lax.scan(body, h, params["layers"])
     return _logits(params, cfg, h[:, -1:]), ServeCache(convs, ssms)
 
 
 def decode_step(params, cfg: ModelConfig, token, cache: ServeCache, pos):
-    h = params["embed"][token]
+    h = _embed(params, token)
 
     def body(h, xs):
         lp, conv, ssm_state = xs
-        y, conv_new, ssm_new = _mixer(
-            lp, cfg, common.rms_norm(h, lp["ln"], cfg.norm_eps),
-            conv_cache=conv, ssm_state=ssm_state, decode=True)
-        return h + y, (conv_new, ssm_new)
+        h, conv_new, ssm_new = _block(lp, cfg, h, conv_cache=conv,
+                                      ssm_state=ssm_state, decode=True)
+        return h, (conv_new, ssm_new)
     h, (convs, ssms) = jax.lax.scan(body, h, (params["layers"], cache.conv, cache.ssm))
     return _logits(params, cfg, h), ServeCache(convs, ssms)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention, common
+from repro.obs import trace as obs_trace
 from repro.sharding.partition import shard_act
 
 
@@ -108,8 +109,9 @@ def _mlp(p, x):
 
 
 def _embed(params, cfg: ModelConfig, tokens):
-    h = params["embed"][tokens] * jnp.sqrt(float(cfg.d_model))
-    return shard_act(h, "batch", "seq", None)
+    with obs_trace.stage("model.embed"):
+        h = params["embed"][tokens] * jnp.sqrt(float(cfg.d_model))
+        return shard_act(h, "batch", "seq", None)
 
 
 def _media_embed(params, cfg: ModelConfig, media):
@@ -119,9 +121,10 @@ def _media_embed(params, cfg: ModelConfig, media):
 
 
 def _logits(params, cfg: ModelConfig, h):
-    h = common.rms_norm(h, params["ln_f"], cfg.norm_eps)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return shard_act(h @ w, "batch", None, "vocab")
+    with obs_trace.stage("model.head"):
+        h = common.rms_norm(h, params["ln_f"], cfg.norm_eps)
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return shard_act(h @ w, "batch", None, "vocab")
 
 
 # ---------------------------------------------------------------------------
@@ -130,6 +133,21 @@ def _logits(params, cfg: ModelConfig, h):
 
 def _apply_layer(lp, cfg: ModelConfig, h, plan, *, positions=None, media=None,
                  mode="train", cache=None, pos=None, cache_len=0):
+    with obs_trace.stage("model.mixer"):
+        a, new_cache = _apply_mixer(lp, cfg, h, plan, positions=positions,
+                                    media=media, mode=mode, cache=cache,
+                                    pos=pos, cache_len=cache_len)
+        h = h + a
+    with obs_trace.stage("model.mlp"):
+        h = h + _mlp(lp["mlp"], common.rms_norm(h, lp["ln2"], cfg.norm_eps))
+    if mode == "train":
+        new_cache = None        # never stack caches through the train scan
+    return h, new_cache
+
+
+def _apply_mixer(lp, cfg: ModelConfig, h, plan, *, positions, media, mode,
+                 cache, pos, cache_len):
+    """One layer's attention block, its pre-norm included: (a, cache)."""
     hd = cfg.resolved_head_dim
     hn = common.rms_norm(h, lp["ln1"], cfg.norm_eps)
     new_cache = cache
@@ -166,11 +184,7 @@ def _apply_layer(lp, cfg: ModelConfig, h, plan, *, positions=None, media=None,
             else:
                 a, new_cache = attention.decode_attention(
                     lp["attn"], hn, cache, pos, **kw)
-    h = h + a
-    h = h + _mlp(lp["mlp"], common.rms_norm(h, lp["ln2"], cfg.norm_eps))
-    if mode == "train":
-        new_cache = None        # never stack caches through the train scan
-    return h, new_cache
+    return a, new_cache
 
 
 def _run_patterned(params, cfg: ModelConfig, h, *, positions=None, media=None,
@@ -229,14 +243,17 @@ def forward(params, cfg: ModelConfig, tokens, media: Optional[jnp.ndarray] = Non
               theta=cfg.rope_theta, qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps)
 
     def body(h, lp):
-        a = attention.self_attention(
-            lp["attn"], common.rms_norm(h, lp["ln1"], cfg.norm_eps),
-            window=cfg.window, **kw)
-        h = h + a
-        h = h + _mlp(lp["mlp"], common.rms_norm(h, lp["ln2"], cfg.norm_eps))
-        # residual stream at the layer boundary: with seq -> 'model'
-        # (sequence parallelism, §Perf A) the saved activations shard 16-way
-        h = shard_act(h, "batch", "seq", None)
+        with obs_trace.stage("model.mixer"):
+            h = h + attention.self_attention(
+                lp["attn"], common.rms_norm(h, lp["ln1"], cfg.norm_eps),
+                window=cfg.window, **kw)
+        with obs_trace.stage("model.mlp"):
+            h = h + _mlp(lp["mlp"],
+                         common.rms_norm(h, lp["ln2"], cfg.norm_eps))
+            # residual stream at the layer boundary: with seq -> 'model'
+            # (sequence parallelism, §Perf A) the saved activations shard
+            # 16-way
+            h = shard_act(h, "batch", "seq", None)
         return h, None
     if cfg.remat:
         body = jax.checkpoint(body)
@@ -271,11 +288,14 @@ def prefill(params, cfg: ModelConfig, tokens, cache_len: int,
               qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps)
 
     def body(h, lp):
-        a, kv = attention.prefill_attention(
-            lp["attn"], common.rms_norm(h, lp["ln1"], cfg.norm_eps),
-            cache_len=max(cache_len, S), window=cfg.window, **kw)
-        h = h + a
-        h = h + _mlp(lp["mlp"], common.rms_norm(h, lp["ln2"], cfg.norm_eps))
+        with obs_trace.stage("model.mixer"):
+            a, kv = attention.prefill_attention(
+                lp["attn"], common.rms_norm(h, lp["ln1"], cfg.norm_eps),
+                cache_len=max(cache_len, S), window=cfg.window, **kw)
+            h = h + a
+        with obs_trace.stage("model.mlp"):
+            h = h + _mlp(lp["mlp"],
+                         common.rms_norm(h, lp["ln2"], cfg.norm_eps))
         return h, kv
     h, caches = jax.lax.scan(body, h, params["layers"])
     return _logits(params, cfg, h[:, -1:]), ServeCache(caches, None)
@@ -353,11 +373,14 @@ def decode_step(params, cfg: ModelConfig, token, cache: ServeCache, pos):
 
     def body(h, xs):
         lp, c = xs
-        a, kvn = attention.decode_attention(
-            lp["attn"], common.rms_norm(h, lp["ln1"], cfg.norm_eps),
-            c, pos, window=cfg.window, **kw)
-        h = h + a
-        h = h + _mlp(lp["mlp"], common.rms_norm(h, lp["ln2"], cfg.norm_eps))
+        with obs_trace.stage("model.mixer"):
+            a, kvn = attention.decode_attention(
+                lp["attn"], common.rms_norm(h, lp["ln1"], cfg.norm_eps),
+                c, pos, window=cfg.window, **kw)
+            h = h + a
+        with obs_trace.stage("model.mlp"):
+            h = h + _mlp(lp["mlp"],
+                         common.rms_norm(h, lp["ln2"], cfg.norm_eps))
         return h, kvn
     h, new_caches = jax.lax.scan(body, h, (params["layers"], cache.layers))
     return _logits(params, cfg, h), ServeCache(new_caches, None)

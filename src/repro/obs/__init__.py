@@ -8,9 +8,10 @@ stacks without touching their math:
   metrics (``RoundMetrics.telemetry``), gated by
   :class:`repro.configs.base.ObsConfig` -- disabled is bit-for-bit the
   un-instrumented engine.
-* :mod:`repro.obs.trace`  -- stage-level tracing: ``jax.named_scope`` +
-  ``jax.profiler.TraceAnnotation`` spans around the round stages and
-  Pallas kernel call sites, plus :class:`ProfileWindow` (the launcher's
+* :mod:`repro.obs.trace`  -- stage-level tracing: ``jax.named_scope``
+  scopes on the round stages, the model's blocks, the local updates, the
+  wire's data movement and the Pallas call sites, so every device op of a
+  profile names its layer, plus :class:`ProfileWindow` (the launcher's
   ``--profile start:stop`` Perfetto capture).
 * :mod:`repro.obs.sinks`  -- the :class:`MetricsSink` registry (memory /
   jsonl / stdout) every launcher reports through; :mod:`repro.obs.log` is

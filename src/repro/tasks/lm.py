@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import common
+from repro.obs import trace as obs_trace
 
 
 class LMBatch(NamedTuple):
@@ -58,18 +59,19 @@ def make_loss_pair(model_forward, cfg: ModelConfig, budget: float = 0.0,
                 out, aux, mtp_logits = out
             else:
                 out, aux = out
-        out = out[:, :-1]
-        targets = batch.tokens[:, 1:]
-        mmask = batch.minority_mask[:, 1:]
-        f = common.cross_entropy(out, targets, mask=1.0 - mmask)
-        if mtp_logits is not None:
-            # MTP: logits at t predict token t+2
-            f = f + mtp_weight * common.cross_entropy(
-                mtp_logits[:, :-1], targets[:, 1:])
-        if aux_constraint and aux is not None:
-            g = aux - budget
-        else:
-            g = common.cross_entropy(out, targets, mask=mmask) - budget
-        return f, g
+        with obs_trace.stage("model.loss"):
+            out = out[:, :-1]
+            targets = batch.tokens[:, 1:]
+            mmask = batch.minority_mask[:, 1:]
+            f = common.cross_entropy(out, targets, mask=1.0 - mmask)
+            if mtp_logits is not None:
+                # MTP: logits at t predict token t+2
+                f = f + mtp_weight * common.cross_entropy(
+                    mtp_logits[:, :-1], targets[:, 1:])
+            if aux_constraint and aux is not None:
+                g = aux - budget
+            else:
+                g = common.cross_entropy(out, targets, mask=mmask) - budget
+            return f, g
 
     return loss_pair

@@ -34,7 +34,7 @@ single-process ``rounds.drive`` oracle on the pinned config surface
 line.  Degraded rounds (dead workers' clients demoted, HT weights
 rescaled mass-conservingly) are bit-identical to the same oracle driven
 with the realized cohort via ``fleet.samplers.FixedSampler`` --
-tests/test_faults.py holds that line.
+tests/test_wire_faults.py holds that line.
 """
 from repro.wire import (bootstrap, coordinator, frames, supervisor, testing,
                         worker)

@@ -1,11 +1,16 @@
-"""Observability tests (ISSUE 8): telemetry-off bit parity across the
+"""Observability tests: telemetry-off bit parity across the
 strategy x compressor x participation x engine matrix, enabled-mode
 state-trajectory invariance, ordered progress callbacks and the on_chunk
 sink hook, LRU-law-predicted slot-store eviction telemetry, the staleness
 histogram under markov departures, the JSONL sink schema round-trip, the
-trailing switch-fraction window, and the sink registry / leveled-log
-contracts."""
+trailing switch-fraction window, the sink registry / leveled-log
+contracts, and the stage scopes: every matmul of the local steps names its
+model block, and the scopes leave the compiled round unchanged."""
+import contextlib
+import importlib.util
 import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +23,7 @@ from repro.configs.base import (AsyncConfig, CompressorConfig, FedConfig,
                                 SwitchConfig)
 from repro.engine import async_rounds, participation, rounds
 from repro.obs import bus, log as obs_log, sinks
+from repro.obs import trace as obs_trace
 from repro.scale import slots
 from repro.tasks import np_classification as npc
 
@@ -394,3 +400,54 @@ class TestSinks:
                 obs_log.set_level("loud")
         finally:
             obs_log.set_level(old)
+
+
+class TestStageScopes:
+    """The benchmark's small cells (``perfbench/tests/conftest.py``) built
+    by its own harness: the dense model with the unfused full eval, the SSM
+    with the fused eval and partial participation, two local steps each."""
+
+    CELLS = {"dense": ("SMALL_DENSE", "smollm-360m", True, {}),
+             "ssm": ("SMALL_SSM", "mamba2-130m", False,
+                     {"clients": 4, "participating": 2})}
+
+    @staticmethod
+    def _compiled_text(which):
+        bench = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "perfbench")
+        spec_ = importlib.util.spec_from_file_location(
+            "perfbench_small_cells", os.path.join(bench, "tests",
+                                                  "conftest.py"))
+        small = importlib.util.module_from_spec(spec_)
+        spec_.loader.exec_module(small)     # puts perfbench/ on sys.path
+        from harness import program, spec
+        model, ref, full_eval, kw = TestStageScopes.CELLS[which]
+        cfg = {"name": which, "model": getattr(small, model),
+               "federation": small.federation(full_eval)}
+        prog = program.Program(cfg, small.mix("quant", local_steps=2, **kw),
+                               spec.load_reference(ref, bench))
+        prog.state(3)
+        return prog.step.as_text()
+
+    @pytest.fixture(scope="class", params=sorted(CELLS))
+    def scoped(self, request):
+        return request.param, self._compiled_text(request.param)
+
+    def test_local_matmuls_carry_a_model_scope(self, scoped):
+        names = re.findall(r" (?:dot|convolution)\(.*op_name=\"([^\"]*)\"",
+                           scoped[1])
+        local = [n for n in names
+                 if "round.eval_round" in n or "round.local_deltas" in n]
+        assert local
+        assert [n for n in local if "model." not in n] == []
+
+    def test_scopes_leave_the_compiled_round_unchanged(self, scoped,
+                                                       monkeypatch):
+        which, text = scoped
+        monkeypatch.setattr(obs_trace, "stage",
+                            lambda name: contextlib.nullcontext())
+        jax.clear_caches()
+        plain = self._compiled_text(which)
+        assert "model.mixer" in text and "model.mixer" not in plain
+        assert obs_trace.strip_metadata(text) == \
+            obs_trace.strip_metadata(plain)

@@ -12,6 +12,7 @@ only one process may load the TPU library at a time, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +110,46 @@ def test_switch_blend(chip):
     g = ((1 << 20,), jnp.float32)
     _compile(chip, lambda a, b, s: switch_blend(a, b, s, interpret=False),
              g, g, ((), jnp.float32))
+
+
+SMALL = 64                  # blocks: enough to lower, fast to compile
+KERNELS = {
+    "quantize_ef_pack": (lambda e, d: quantize_ef_pack(e, d, 8,
+                                                       interpret=False),
+                         ((2, SMALL, 1024), jnp.float32),
+                         ((2, SMALL, 1024), jnp.float32)),
+    "quantize_ef": (lambda e, d: quantize_ef(e, d, 8, interpret=False),
+                    ((SMALL, 1024), jnp.float32),
+                    ((SMALL, 1024), jnp.float32)),
+    "unpack_mma": (lambda w, s, wt: unpack_mma(w, s, wt, 8, 1024,
+                                               interpret=False),
+                   ((2, SMALL, 256), jnp.uint32), ((2, SMALL), jnp.float32),
+                   ((2,), jnp.float32)),
+    "block_topk": (lambda x: block_topk(x, 102, interpret=False),
+                   ((2, SMALL, 1024), jnp.float32)),
+    "scatter_agg": (lambda v, i, w: scatter_agg(v, i, w, 1024,
+                                                interpret=False),
+                    ((2, SMALL, 102), jnp.float32),
+                    ((2, SMALL, 102), jnp.uint16), ((2,), jnp.float32)),
+    "segment_rows": (lambda r, s: segment_rows(r, s, 2, interpret=False),
+                     ((2, 1 << 16), jnp.float32), ((2,), jnp.int32)),
+    "switch_blend": (lambda a, b, s: switch_blend(a, b, s, interpret=False),
+                     ((1 << 16,), jnp.float32), ((1 << 16,), jnp.float32),
+                     ((), jnp.float32)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_custom_call_named_after_kernel(chip, name):
+    """Each kernel's launch is the custom call ``<kernel>`` or
+    ``<kernel>.<n>`` (its ``pallas_call`` ``name=``), also under a caller's
+    scope: the name the benchmark's roofline readers match."""
+    fn, *shapes = KERNELS[name]
+
+    def caller(*args):
+        with jax.named_scope("round.encode_reduce"):
+            return fn(*args)
+    text = _compile(chip, caller, *shapes).as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^=]* custom-call\(", text)
+    assert calls and all(re.fullmatch(re.escape(name) + r"(\.\d+)?", c)
+                         for c in calls), calls
