@@ -143,13 +143,19 @@ def kernel_phase(jax, jnp, np):
     close(acc, acc_ref, 1e-5, 1e-6)
     log("unpack_mma: matches the tensordot plan")
 
-    # block_topk vs lax.top_k: equal offsets, equal values
+    # block_topk vs lax.top_k: the same offsets, bitwise-equal values; the
+    # kernel holds them in ascending offset, lax.top_k by magnitude
     vals, idx = block_topk(delta, k)           # slot rows padded to 128
     vals_ref, idx_ref = jax.jit(
         lambda a: payloads.select_topk_blocks(a, k, False))(delta)
-    check(bool(jnp.all(idx[..., :k] == idx_ref.astype(jnp.int32))),
+    order = jnp.argsort(idx_ref, axis=-1)
+    vals_ref = jnp.take_along_axis(vals_ref, order, axis=-1)
+    idx_ref = jnp.take_along_axis(idx_ref.astype(jnp.int32), order, axis=-1)
+    check(bool(jnp.all(idx[..., :k] == idx_ref)),
           "block_topk offsets differ from lax.top_k")
-    close(vals[..., :k], vals_ref, 0.0, 0.0)
+    check(np.array_equal(np.asarray(vals[..., :k]).view(np.int32),
+                         np.asarray(vals_ref).view(np.int32)),
+          "block_topk values differ from lax.top_k")
     log(f"block_topk: offsets and values equal to lax.top_k (k={k})")
 
     # scatter_agg vs the XLA scatter-add plan

@@ -510,8 +510,9 @@ class TopKTransport(_BlockSelectTransport):
 
     ref: global per-leaf argsort selection (giant leaves fall back to the
     blockwise threshold path); packed: blockwise (values, indices) payload;
-    pallas: blockwise selection inside the ``topk_block`` kernel (k masked
-    argmax passes over a VMEM-resident block), emitting the same payload."""
+    pallas: blockwise selection inside the ``topk_block`` kernel (an exact
+    bit-pattern threshold and an order-keeping compaction over a
+    VMEM-resident block), emitting the same payload in ascending offset."""
 
     kind = "topk"
 

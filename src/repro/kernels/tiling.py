@@ -47,20 +47,25 @@ def row_tile(rows: int, width: int, budget: int = TILE_BYTES,
     return rows if rows <= fit else fit
 
 
-def for_row_chunks(rows: int, body) -> None:
-    """Call ``body(rows_slice)`` over a tile's rows in chunks of 8 sublanes
-    (one chunk when ``rows`` is not a multiple of 8).  Mosaic emits
+def for_row_chunks(rows: int, body, chunk: int = SUBLANE) -> None:
+    """Call ``body(rows_slice)`` over a tile's rows in chunks of ``chunk``
+    rows (a multiple of 8 sublanes), then once over the rows left; one call
+    over all rows when ``rows`` is not a multiple of 8.  Mosaic emits
     straight-line code for every vreg an op touches, so looping over small
     chunks keeps a kernel's code and compile time small while its DMA tile
     stays large."""
     if rows % SUBLANE:
         body(pl.ds(0, rows))
         return
+    n, tail = divmod(rows, chunk)
 
     def step(i, carry):
-        body(pl.ds(pl.multiple_of(i * SUBLANE, SUBLANE), SUBLANE))
+        body(pl.ds(pl.multiple_of(i * chunk, chunk), chunk))
         return carry
-    jax.lax.fori_loop(0, rows // SUBLANE, step, 0)
+    if n:
+        jax.lax.fori_loop(0, n, step, 0)
+    if tail:
+        body(pl.ds(n * chunk, tail))
 
 
 def rows_to_lanes(cols):

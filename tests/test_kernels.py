@@ -12,21 +12,38 @@ from repro.kernels.switch_blend import switch_blend
 from repro.kernels.topk_block import block_topk
 
 
+def _topk_fills(key, shape):
+    """Blocks to select from: normal draws, then tie-heavy ones (many equal
+    |x|, +-x pairs, all |x| equal) and all-zero ones holding +-0.0."""
+    x = jax.random.normal(key, shape)
+    signs = jnp.where(jax.random.bernoulli(key, 0.5, shape), 1.0, -1.0)
+    zeros = jnp.where(signs > 0, 0.0, -0.0)
+    return {"normal": x, "ties": jnp.round(x * 2) / 2, "pm": signs,
+            "zeros": zeros}
+
+
 @pytest.mark.parametrize("nblocks,block,k", [
-    (1, 8, 2), (4, 64, 7), (2, 128, 16), (3, 256, 26), (2, 512, 51)])
+    (1, 8, 2), (4, 64, 7), (2, 128, 16), (3, 256, 26), (2, 512, 51),
+    # the smollm-360m wire's unaligned widths, 40 rows: one 32-row chunk
+    # and a tail of 8
+    (3, 960, 96), (2, 640, 64), (40, 320, 32), (2, 960, 1), (2, 320, 319)])
 @pytest.mark.parametrize("dtype", [jnp.float32])
 def test_topk_shapes(nblocks, block, k, dtype, key):
-    x = jax.random.normal(key, (nblocks, block), dtype)
-    v, i = block_topk(x, k)
-    v, i = v[:, :k], i[:, :k]       # slot rows come padded to 128 lanes
-    vr, ir = ref.block_topk_ref(x, k)
-    # same selected magnitude set per block (order may differ on ties)
-    np.testing.assert_allclose(
-        np.sort(np.abs(np.asarray(v)), -1), np.sort(np.abs(np.asarray(vr)), -1),
-        rtol=1e-6, atol=1e-6)
-    # indices point at the values they claim
-    gathered = np.take_along_axis(np.asarray(x), np.asarray(i), -1)
-    np.testing.assert_allclose(gathered, np.asarray(v), rtol=1e-6)
+    for fill, x in _topk_fills(key, (nblocks, block)).items():
+        x = x.astype(dtype)
+        v, i = map(np.asarray, block_topk(x, k))
+        vr, ir = map(np.asarray, ref.block_topk_ref(x, k))
+        # the same offsets as lax.top_k, held in ascending offset, with
+        # bitwise-equal values (ties to the lowest offset, -0.0 kept)
+        order = np.argsort(ir, -1)
+        np.testing.assert_array_equal(i[:, :k],
+                                      np.take_along_axis(ir, order, -1),
+                                      err_msg=fill)
+        np.testing.assert_array_equal(
+            v[:, :k].view(np.int32),
+            np.take_along_axis(vr, order, -1).view(np.int32), err_msg=fill)
+        # slot rows come padded to 128 lanes with zeros
+        assert not v[:, k:].any() and not i[:, k:].any(), fill
 
 
 def test_topk_bf16(key):

@@ -31,7 +31,7 @@ NBLOCKS = 353_792           # ~362M / 1024: one full-width smollm-360m buffer
 CLIENTS = 2                 # encode kernels see [clients, nblocks, block]
 # the block widths a smollm-360m wire layout runs at (d_model 960, kv 320,
 # d_ff 2560 -> 640) next to the aligned default
-BLOCKS = (1024, 960, 320)
+BLOCKS = (1024, 960, 640, 320)
 
 
 @pytest.fixture(scope="module")
